@@ -23,11 +23,13 @@ MABS analog of the paper's T(s, n) subset-size sweep — it runs in the
 single-device subprocess so its timings share the engine rows'
 conditions.
 
-Device counts are realized per subprocess via
-``--xla_force_host_platform_device_count`` so one invocation sweeps
-several mesh sizes on CPU; on a real TPU backend the script uses the
-actual devices instead (forcing host-platform devices would hide them)
-and sweeps prefixes of ``jax.devices()``.
+On a TPU the sweep runs in this one process on the real devices (a
+parent that has touched JAX holds the chip, so it starts no children),
+and it fails when JAX finds no TPU. The CPU rehearsal is explicit:
+with ``JAX_PLATFORMS=cpu`` the parent stays off JAX and runs one
+subprocess per device count, realized via
+``--xla_force_host_platform_device_count`` — virtual devices that check
+control flow and counts, never speed.
 
 Emits BENCH_engine.json next to the repo root (or --out PATH):
 
@@ -36,7 +38,8 @@ Emits BENCH_engine.json next to the repo root (or --out PATH):
    "total_waves", "mean_parallelism", "seconds", ...comm/overlap...},
    {"kind": "tn", "model", "topology", "n_agents", "window", ...}, ...]}
 
-Run:  PYTHONPATH=src python benchmarks/engine_sweep.py [--quick]
+Run:  PYTHONPATH=src python benchmarks/engine_sweep.py [--quick]            (TPU)
+      JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/engine_sweep.py --quick
 """
 from __future__ import annotations
 
@@ -162,7 +165,9 @@ def _inner(args) -> None:
     import jax
 
     from repro.obs.profiler import profile_session
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     logdir = (os.path.join(args.profile, f"d{jax.device_count()}")
               if args.profile else None)
     with profile_session(logdir):
@@ -324,13 +329,26 @@ def main():
                 + ([] if with_tn else ["--no-tn-sweep"])
                 + (["--tn-only"] if tn_only else []))
 
-    import jax  # after arg parsing: the parent keeps its default devices
-
-    on_tpu = jax.default_backend() == "tpu"
+    cpu_rehearsal = os.environ.get("JAX_PLATFORMS") == "cpu"
     rows = []
-    if on_tpu:
-        # guarded TPU path: host-platform device forcing would hide the
-        # real chips, so run the sweep in-process on the actual mesh
+    if cpu_rehearsal:
+        # the parent stays off JAX until every child has exited
+        for d in args.devices:
+            # the T(W, n) rows are single-device by construction: attach
+            # them to the d=1 subprocess so timings share its conditions
+            rows.extend(_spawn(d, inner_argv(args.tn_sweep and d == 1)))
+        if args.tn_sweep and 1 not in args.devices:
+            # no d=1 lane requested: run the T(W, n) rows in their own
+            # single-device subprocess rather than silently dropping them
+            rows.extend(_spawn(1, inner_argv(True, tn_only=True)))
+    else:
+        import jax
+
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            raise SystemExit(
+                f"engine_sweep: JAX found no TPU (platform {platform!r}); "
+                "set JAX_PLATFORMS=cpu for the virtual-device CPU rehearsal")
         import io
         from contextlib import redirect_stdout
 
@@ -340,15 +358,6 @@ def main():
         rows = [json.loads(line[4:]) for line in buf.getvalue().splitlines()
                 if line.startswith("ROW ")]
         print(buf.getvalue(), end="")
-    else:
-        for d in args.devices:
-            # the T(W, n) rows are single-device by construction: attach
-            # them to the d=1 subprocess so timings share its conditions
-            rows.extend(_spawn(d, inner_argv(args.tn_sweep and d == 1)))
-        if args.tn_sweep and 1 not in args.devices:
-            # no d=1 lane requested: run the T(W, n) rows in their own
-            # single-device subprocess rather than silently dropping them
-            rows.extend(_spawn(1, inner_argv(True, tn_only=True)))
 
     from repro.obs import provenance
 
@@ -365,8 +374,8 @@ def main():
             # from the rows, not the request: on TPU the sweep runs on the
             # one real mesh regardless of --devices
             "device_counts": sorted({r["n_devices"] for r in engine_rows}),
-            "backend": "tpu" if on_tpu else "cpu",
-            "virtual_devices": not on_tpu,
+            "backend": "cpu" if cpu_rehearsal else "tpu",
+            "virtual_devices": cpu_rehearsal,
             "strict": True,
             "tn_sweep": {"families": list(TN_FAMILIES),
                          "n_agents": list(TN_AGENTS),

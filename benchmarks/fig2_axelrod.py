@@ -18,7 +18,7 @@ import jax
 import numpy as np
 
 from repro.core import DESCosts, ProtocolConfig, simulate_protocol
-from repro.core.wavefront import WavefrontRunner
+from repro.engine import make_engine
 from repro.mabs.axelrod import AxelrodConfig, AxelrodModel
 from repro.utils.timing import median_time
 
@@ -30,8 +30,9 @@ def calibrate_task_cost(n_agents=10_000, features=(3, 50, 150, 300, 500)):
     for f in features:
         m = AxelrodModel(AxelrodConfig(n_agents=n_agents, n_features=f))
         st = m.init_state(jax.random.key(0))
-        runner = WavefrontRunner(m, window=256)
-        t = median_time(lambda: runner._step(st, jax.random.key(1), 0),
+        eng = make_engine("wavefront", m, window=256)
+        # one window of 256 tasks: schedule + waves, fenced on the state
+        t = median_time(lambda: eng.run(st, 256, seed=1)[0],
                         repeats=3, warmup=1)
         xs.append(f)
         ys.append(t / 256.0)      # per-task cost of the vectorized engine

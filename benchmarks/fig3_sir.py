@@ -18,7 +18,7 @@ import jax
 import numpy as np
 
 from repro.core import DESCosts, ProtocolConfig, simulate_protocol
-from repro.core.wavefront import WavefrontRunner
+from repro.engine import make_engine
 from repro.mabs.sir import SIRConfig, SIRModel
 from repro.utils.timing import median_time
 
@@ -29,8 +29,9 @@ def calibrate_task_cost(n_agents=4_000, sizes=(10, 50, 200, 1000)):
         m = SIRModel(SIRConfig(n_agents=n_agents, k=14, subset_size=s))
         st = m.init_state(jax.random.key(0))
         w = min(64, 2 * m.cfg.n_subsets)
-        runner = WavefrontRunner(m, window=w)
-        t = median_time(lambda: runner._step(st, jax.random.key(1), 0),
+        eng = make_engine("wavefront", m, window=w)
+        # one window of w tasks: schedule + waves, fenced on the state
+        t = median_time(lambda: eng.run(st, w, seed=1)[0],
                         repeats=3, warmup=1)
         xs.append(s)
         ys.append(t / w)

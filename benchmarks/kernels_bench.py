@@ -26,15 +26,14 @@ def bench_conflict():
 
 def bench_axelrod_wave():
     from repro.mabs.axelrod import AxelrodConfig, AxelrodModel
-    from repro.core.wavefront import WavefrontRunner
+    from repro.engine import make_engine
 
     rows = []
     for f_ in (3, 150, 500):
         m = AxelrodModel(AxelrodConfig(n_agents=10_000, n_features=f_))
         st = m.init_state(jax.random.key(0))
-        runner = WavefrontRunner(m, window=256)
-        t = median_time(lambda: runner._step(st, jax.random.key(1), 0),
-                        repeats=3)
+        eng = make_engine("wavefront", m, window=256)
+        t = median_time(lambda: eng.run(st, 256, seed=1)[0], repeats=3)
         rows.append((f"axelrod_window256_F{f_}", t * 1e6,
                      f"{256/t:.0f} tasks/s"))
     return rows
@@ -42,16 +41,15 @@ def bench_axelrod_wave():
 
 def bench_sir_wave():
     from repro.mabs.sir import SIRConfig, SIRModel
-    from repro.core.wavefront import WavefrontRunner
+    from repro.engine import make_engine
 
     rows = []
     for s in (10, 100, 1000):
         m = SIRModel(SIRConfig(n_agents=4_000, k=14, subset_size=s))
         st = m.init_state(jax.random.key(0))
         w = min(64, 2 * m.cfg.n_subsets)
-        runner = WavefrontRunner(m, window=w)
-        t = median_time(lambda: runner._step(st, jax.random.key(1), 0),
-                        repeats=3)
+        eng = make_engine("wavefront", m, window=w)
+        t = median_time(lambda: eng.run(st, w, seed=1)[0], repeats=3)
         rows.append((f"sir_window{w}_s{s}", t * 1e6,
                      f"{w*s/t:.0f} agent-updates/s"))
     return rows

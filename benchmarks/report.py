@@ -333,8 +333,8 @@ def bench_explain(bench, path):
 
     roof = mabs_roofline_rows(bench)
     if roof:
-        backend = bench.get("meta", {}).get("backend", "cpu")
-        print(f"\n#### MABS roofline (compiled costs, {backend} peaks; "
+        kind = bench["meta"]["provenance"]["device_kind"]
+        print(f"\n#### MABS roofline (compiled costs, {kind} peaks; "
               "bound = max of the three terms)\n")
         print("| model | engine | W | dev | executor | compute s "
               "| memory s | collective s | dominant | bound s "

@@ -175,15 +175,28 @@ def build_table(artifact_dir: str = ARTIFACT_DIR, mesh: str | None = None,
 # --------------------------------------------------------------------------
 # MABS engine roofline + T(W, n) cost-model fit (BENCH_engine.json rows)
 
-#: roofline peaks per backend. TPU: the v5e constants above. CPU: order-
-#: of-magnitude host figures (a few-core AVX box; virtual-device "links"
-#: are memcpys through the same memory system) — the CPU roofline ranks
-#: bound terms and engines against each other, it is not a calibrated
-#: absolute bound.
-MABS_PEAKS = {
-    "tpu": {"flops": PEAK_FLOPS, "mem_bw": HBM_BW, "link_bw": LINK_BW},
-    "cpu": {"flops": 5e10, "mem_bw": 2e10, "link_bw": 1e10},
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``
+#: (what ``repro.obs.provenance`` stamps into a BENCH file's meta). TPU
+#: v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393
+#: TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+#: interconnect. A device kind missing here is an error, never a default:
+#: a CPU or virtual-device run has no device roofline.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "mem_bw": 819e9, "link_bw": 200e9,
+                    "source": 'Google Cloud documentation, "TPU v5e"'},
 }
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; raises for a kind with no
+    published figures in ``DEVICE_PEAKS``."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); a roofline needs a chip "
+            "run") from None
 
 
 def mabs_roofline_rows(bench: dict) -> list[dict]:
@@ -191,9 +204,10 @@ def mabs_roofline_rows(bench: dict) -> list[dict]:
     telemetry (the ``cost`` field captured by engine_sweep). Per-call
     cost_analysis FLOPs/bytes count the wave loop's body once, so both
     scale by the executed wave count; the collective term is the
-    ledger-cross-checked HLO receive-byte total for the whole run."""
-    peaks = MABS_PEAKS.get(bench.get("meta", {}).get("backend", "cpu"),
-                           MABS_PEAKS["cpu"])
+    ledger-cross-checked HLO receive-byte total for the whole run.
+    Peaks come from ``device_peaks`` of the run's device kind."""
+    meta = bench.get("meta", {})
+    peaks = device_peaks((meta.get("provenance") or {}).get("device_kind"))
     out = []
     for r in bench.get("rows", []):
         c = r.get("cost")

@@ -26,9 +26,11 @@ import jax.numpy as jnp
 from repro.core import ProtocolConfig, run_engine
 from repro.mabs.voter import VoterModel
 from repro.topology import watts_strogatz
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())}")
     model = VoterModel(watts_strogatz(1024, 4, 0.1, jax.random.key(0)))
     cfg = ProtocolConfig(window=256, strict=True)

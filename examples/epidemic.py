@@ -9,9 +9,11 @@ import numpy as np
 from repro.core import ProtocolConfig, run_wavefront, simulate_protocol
 from repro.core.wavefront import window_schedule_stats
 from repro.mabs.sir import SIRConfig, SIRModel
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = SIRConfig(n_agents=2_000, k=14, subset_size=50,
                     p_si=0.8, p_ir=0.1, p_rs=0.3, i0=0.02)
     model = SIRModel(cfg)

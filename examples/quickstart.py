@@ -13,9 +13,11 @@ import jax.numpy as jnp
 from repro.core import ProtocolConfig, run_oracle, run_wavefront, \
     simulate_protocol
 from repro.mabs.axelrod import AxelrodConfig, AxelrodModel
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     model = AxelrodModel(AxelrodConfig(n_agents=500, n_features=20, q=3))
     state0 = model.init_state(jax.random.key(0))
     n_tasks = 2_000

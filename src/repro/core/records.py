@@ -152,7 +152,7 @@ def wave_levels(conflicts: jax.Array, valid: jax.Array, *,
     window, and commuting tasks may be reordered freely (paper §3.2).
 
     Implementation lives in kernels/levels — the blocked Pallas kernel on
-    TPU, the reference ``lax.scan`` elsewhere (backend auto-detect, like
+    TPU, the reference ``lax.scan`` elsewhere (chosen at trace time, like
     the conflict kernel).
     """
     from repro.kernels.levels.ops import wave_levels as _wave_levels

@@ -142,7 +142,7 @@ class WindowedEngine(Engine):
     def _schedule_window(self, base_key, start, count):
         """The shared scheduling recipe: create one window of tasks and
         reduce it to wave levels (conflict + levels kernels, backend
-        auto-detected). Returns (recipes, valid, levels)."""
+        chosen at trace time). Returns (recipes, valid, levels)."""
         from repro.core.records import wave_levels
 
         recipes, valid, conf = self._schedule_window_ov(
@@ -278,9 +278,13 @@ class WindowedEngine(Engine):
 
     # ------------------------------------------------- cross-window overlap
     def _make_boundary(self):
-        """Jitted boundary step for one window transition k -> k+1:
-        cross-window record check, carry frontier, floored re-leveling,
-        and the per-boundary overlap statistics."""
+        """Jitted boundary step (``_boundary_step``)."""
+        return jax.jit(self._boundary_step())
+
+    def _boundary_step(self):
+        """Boundary step for one window transition k -> k+1: cross-window
+        record check, carry frontier, floored re-leveling, and the
+        per-boundary overlap statistics."""
         from repro.core.records import (
             carry_frontier,
             cross_window_conflicts,
@@ -307,7 +311,7 @@ class WindowedEngine(Engine):
                       jnp.max(jnp.where(valid_b, carry, 0), initial=0))
             return lv_b, bstats
 
-        return jax.jit(boundary)
+        return boundary
 
     def _levels0(self, conf, valid):
         """First window's levels (no predecessor -> no carry floor)."""

@@ -100,7 +100,6 @@ from repro.distributed.sharding import (
 )
 from repro.engine.base import WindowedEngine, register_engine
 from repro.obs.profiler import annotate
-from repro.utils.compat import shard_map
 
 
 @register_engine
@@ -338,25 +337,25 @@ class ShardedEngine(WindowedEngine):
                 (jnp.int32(0), local_state))
             return local_state, n_waves
 
-        window_sharded = shard_map(
+        window_sharded = jax.shard_map(
             window_local, mesh=self.mesh,
             in_specs=(P(AXIS), P(), P(), P(), P()),
             out_specs=(P(AXIS), P()),
             check_vma=False)
 
-        window_split_sharded = shard_map(
+        window_split_sharded = jax.shard_map(
             window_split_local, mesh=self.mesh,
             in_specs=(P(AXIS), P(), P(), P(), P(), P()),
             out_specs=(P(AXIS), P()),
             check_vma=False)
 
-        window_pair_sharded = shard_map(
+        window_pair_sharded = jax.shard_map(
             window_pair_local, mesh=self.mesh,
             in_specs=(P(AXIS), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(AXIS), P()),
             check_vma=False)
 
-        window_pair_split_sharded = shard_map(
+        window_pair_split_sharded = jax.shard_map(
             window_pair_split_local, mesh=self.mesh,
             in_specs=(P(AXIS), P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(AXIS), P()),
@@ -483,6 +482,15 @@ class ShardedEngine(WindowedEngine):
         else:
             self._gather_rows = halo_width if use_halo else n_pad
         self._built_for = n_agents
+
+    def _make_boundary(self):
+        """The boundary step runs replicated on every mesh device: from
+        the second boundary on, the tail's rebased levels come out of the
+        mesh-wide pair executor, and a Mosaic kernel under a multi-device
+        jit must sit inside a shard_map (it cannot be auto-partitioned)."""
+        return jax.jit(jax.shard_map(
+            self._boundary_step(), mesh=self.mesh, in_specs=P(),
+            out_specs=P(), check_vma=False))
 
     # ------------------------------------------------------- state hooks
     def _prepare_state(self, state):

@@ -3,11 +3,11 @@
 
 Streams the chain through windows of W tasks: each window is scheduled
 (prefix-conflict matrix through the conflict kernel, wave levels through
-the levels kernel — backend auto-detected) and executed one vectorized
-wave at a time. By default the window boundary is a conservative
-barrier, so cross-window ordering is trivially preserved; the shared
-``WindowedEngine`` loop overlaps window t+1's scheduling with window t's
-execution.
+the levels kernel — backend chosen at trace time) and executed one
+vectorized wave at a time. By default the window boundary is a
+conservative barrier, so cross-window ordering is trivially preserved;
+the shared ``WindowedEngine`` loop overlaps window t+1's scheduling with
+window t's execution.
 
 With ``overlap=True`` (or the ``wavefront_overlap`` registry entry) the
 barrier falls: window k+1 is re-leveled against the carry-over conflict

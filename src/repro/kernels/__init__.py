@@ -15,17 +15,47 @@ Kernels:
   sir      — one wave of ring-graph SIRS subset updates (paper §4.2)
   wkv6     — RWKV6 data-dependent-decay time-mix (chunked recurrence)
   flash    — fused attention (causal / sliding-window), online softmax
+
+Backend choice happens when a call is traced, from the platform JAX runs
+on at that moment — never at import, so importing the package starts no
+backend. On a TPU the scheduling kernels are the compiled Pallas kernels;
+elsewhere the fused jnp / scan fallbacks, or the Pallas interpreter when
+``backend="pallas"`` is forced.
 """
+from __future__ import annotations
 
-ON_TPU = False
-try:  # pragma: no cover - resolved at import time
-    import jax
+import jax
 
-    ON_TPU = jax.default_backend() == "tpu"
-except Exception:  # pragma: no cover
-    pass
+#: how each scheduling kernel was last traced: ``{"conflict_matrix" |
+#: "conflict_block" | "wave_levels": "pallas" | "interpret" | "jnp"}``
+#: ("pallas" = compiled Mosaic kernel). Written by ``resolve``; the chip
+#: smoke test reads it to refuse a run that fell back off the kernels.
+RESOLVED: dict[str, str] = {}
+
+
+def on_tpu() -> bool:
+    """Whether calls traced now run on a TPU (the default backend)."""
+    return jax.default_backend() == "tpu"
 
 
 def interpret_default() -> bool:
-    """pallas interpret mode: Python interpreter on CPU, compiled on TPU."""
-    return not ON_TPU
+    """pallas interpret mode: Python interpreter off TPU, compiled on TPU."""
+    return not on_tpu()
+
+
+def resolve(kernel: str, backend: str | None,
+            interpret: bool | None) -> tuple[str, bool]:
+    """Pick (backend, interpret) for one scheduling-kernel call at trace
+    time: ``backend=None`` means the compiled Pallas kernel on TPU and the
+    jnp fallback elsewhere; ``interpret=None`` means compiled on TPU and
+    the interpreter elsewhere. Records the choice in ``RESOLVED``."""
+    tpu = on_tpu()
+    if backend is None:
+        backend = "pallas" if tpu else "jnp"
+    if interpret is None:
+        interpret = not tpu
+    if backend not in ("pallas", "jnp"):
+        raise ValueError(f"unknown {kernel} backend {backend!r}")
+    RESOLVED[kernel] = ("interpret" if backend == "pallas" and interpret
+                        else backend)
+    return backend, bool(interpret)

@@ -110,9 +110,10 @@ def conflict_matrix_pallas(read_ids, write_ids, valid, *, strict: bool = True,
     """read_ids [W, nr] int32, write_ids [W, nw] int32 (−1 = unused slot),
     valid [W] bool. Returns [W, W] int32 prefix-conflict matrix.
 
-    interpret=None auto-detects the backend: compiled on TPU, Pallas
-    interpreter elsewhere. Any window size is accepted; non-multiples of
-    the tile size are padded to the next tile boundary internally.
+    interpret=None picks by the platform the call is traced for: compiled
+    on TPU, the Pallas interpreter elsewhere. Any window size is accepted;
+    non-multiples of the tile size are padded to the next tile boundary
+    internally.
     """
     if interpret is None:
         from repro.kernels import interpret_default

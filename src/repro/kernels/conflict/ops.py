@@ -1,10 +1,11 @@
 """Public wrapper for the prefix-conflict computation.
 
 Routes between the Pallas kernel (compiled on TPU; interpreter elsewhere)
-and a vectorized pure-jnp implementation. On CPU the jnp path is the
-default: Pallas interpret mode re-traces the tile loop in Python and is
-orders of magnitude slower than one fused XLA elementwise kernel, while on
-TPU the tiled Pallas kernel keeps each [B, B] block in VMEM.
+and a vectorized pure-jnp implementation, chosen when the call is traced
+(``repro.kernels.resolve``). On CPU the jnp path is the default: Pallas
+interpret mode re-traces the tile loop in Python and is orders of
+magnitude slower than one fused XLA elementwise kernel, while on TPU the
+tiled Pallas kernel keeps each [B, B] block in VMEM.
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.model import footprint_conflicts
-from repro.kernels import ON_TPU
+from repro.kernels import resolve
 from repro.kernels.conflict.conflict import (
     conflict_block_pallas,
     conflict_matrix_pallas,
@@ -45,24 +46,21 @@ def conflict_matrix(read_ids, write_ids, valid, *, strict: bool = True,
     slots; valid [W] bool masks padded window entries.
 
     backend: None  — auto: Pallas (compiled) on TPU, jnp elsewhere;
-             "pallas" — force the kernel (interpret per ``interpret`` arg,
-                        itself auto-detected when None);
+             "pallas" — force the kernel (interpret per ``interpret`` arg:
+                        None = compiled on TPU, interpreter elsewhere);
              "jnp"    — force the vectorized fallback.
     """
     read_ids = jnp.asarray(read_ids, jnp.int32)
     write_ids = jnp.asarray(write_ids, jnp.int32)
     valid = jnp.asarray(valid, bool)
-    if backend is None:
-        backend = "pallas" if ON_TPU else "jnp"
+    backend, interpret = resolve("conflict_matrix", backend, interpret)
     with annotate("protocol.conflict_matrix"):
         if backend == "jnp":
             return conflict_matrix_jnp(read_ids, write_ids, valid,
                                        strict=strict)
-        if backend == "pallas":
-            out = conflict_matrix_pallas(read_ids, write_ids, valid,
-                                         strict=strict, interpret=interpret)
-            return out.astype(bool)
-    raise ValueError(f"unknown conflict backend {backend!r}")
+        out = conflict_matrix_pallas(read_ids, write_ids, valid,
+                                     strict=strict, interpret=interpret)
+        return out.astype(bool)
 
 
 @functools.partial(jax.jit, static_argnames=("strict",))
@@ -91,7 +89,8 @@ def conflict_block(reads_i, writes_i, reads_j, writes_j, valid_i, valid_j,
     k's not-yet-drained tail (core/records.cross_window_conflicts).
 
     backend: None  — auto: Pallas (compiled) on TPU, jnp elsewhere;
-             "pallas" — force the rectangular-tile kernel;
+             "pallas" — force the rectangular-tile kernel (interpret as
+                        in ``conflict_matrix``);
              "jnp"    — force the vectorized fallback.
     """
     reads_i = jnp.asarray(reads_i, jnp.int32)
@@ -100,15 +99,12 @@ def conflict_block(reads_i, writes_i, reads_j, writes_j, valid_i, valid_j,
     writes_j = jnp.asarray(writes_j, jnp.int32)
     valid_i = jnp.asarray(valid_i, bool)
     valid_j = jnp.asarray(valid_j, bool)
-    if backend is None:
-        backend = "pallas" if ON_TPU else "jnp"
+    backend, interpret = resolve("conflict_block", backend, interpret)
     with annotate("protocol.conflict_block"):
         if backend == "jnp":
             return conflict_block_jnp(reads_i, writes_i, reads_j, writes_j,
                                       valid_i, valid_j, strict=strict)
-        if backend == "pallas":
-            out = conflict_block_pallas(reads_i, writes_i, reads_j, writes_j,
-                                        valid_i, valid_j, strict=strict,
-                                        interpret=interpret)
-            return out.astype(bool)
-    raise ValueError(f"unknown conflict backend {backend!r}")
+        out = conflict_block_pallas(reads_i, writes_i, reads_j, writes_j,
+                                    valid_i, valid_j, strict=strict,
+                                    interpret=interpret)
+        return out.astype(bool)

@@ -24,6 +24,15 @@ exactly the ordering the recurrence needs.
 Semantics match the scan reference for *arbitrary* inputs: entries at or
 above the diagonal and entries pointing at invalid tasks contribute the
 initial level -1, i.e. nothing.
+
+Mosaic constraints. The diagonal block is a dynamic lane slice
+``conf_ref[:, pl.ds(base, B)]``, which compiles only when ``base`` is a
+provable multiple of 128; the window is therefore always padded with
+invalid slots to a multiple of B = 128 (a W = 64 window runs as one
+128-block). The double-buffered (B, W) int32 conflict panel counts
+against Mosaic's scoped VMEM limit, so the kernel's scoped VMEM grows
+linearly in W; windows past ``MAX_WINDOW`` are refused up front with an
+error naming the limit, instead of failing inside Mosaic.
 """
 from __future__ import annotations
 
@@ -34,6 +43,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK = 128
+
+#: default scoped VMEM of one TPU v5e TensorCore (Mosaic's limit when a
+#: kernel sets none)
+VMEM_LIMIT = 16 * 2**20
+
+
+def vmem_bytes(wp: int, block: int = BLOCK) -> int:
+    """Scoped VMEM the kernel needs for a window padded to ``wp`` slots:
+    the double-buffered (block, wp) int32 panel plus ~48 B per slot of
+    Mosaic's own scratch (fitted to its scoped-allocation report on v5e:
+    16.08 MiB at wp = 15744, 16.75 MiB at wp = 16384)."""
+    return 2 * block * wp * 4 + 48 * wp
+
+
+#: largest window (a multiple of BLOCK) whose panel fits VMEM_LIMIT
+MAX_WINDOW = VMEM_LIMIT // vmem_bytes(1) // BLOCK * BLOCK
 
 
 def _kernel(conf_ref, valid_ref, base_ref, out_ref):
@@ -83,17 +108,25 @@ def wave_levels_pallas(conflicts, valid, base=None, *,
     floor — the overlapped engines' cross-window carry frontier; None
     means no floor (all-zero), the classic recurrence.
 
-    interpret=None auto-detects the backend: compiled on TPU, Pallas
-    interpreter elsewhere. Any window size is accepted; non-multiples of
-    the tile size are padded with invalid slots internally.
+    interpret=None picks by the platform the call is traced for: compiled
+    on TPU, the Pallas interpreter elsewhere. Windows up to MAX_WINDOW are
+    accepted; they are padded with invalid slots to a multiple of the
+    128-lane block internally.
     """
     if interpret is None:
         from repro.kernels import interpret_default
 
         interpret = interpret_default()
+    assert block % 128 == 0, "the diagonal-block lane slice needs 128-multiples"
     w = conflicts.shape[0]
-    b = min(block, w)
-    wp = -(-w // b) * b  # next multiple of the tile size
+    b = block
+    wp = -(-w // b) * b  # next multiple of the block
+    if vmem_bytes(wp, b) > VMEM_LIMIT:
+        raise ValueError(
+            f"wave_levels_pallas: window {w} needs "
+            f"{vmem_bytes(wp, b) / 2**20:.2f} MiB of scoped VMEM, over the "
+            f"{VMEM_LIMIT >> 20} MiB limit; the largest window this kernel "
+            f"takes is {MAX_WINDOW}")
     conf = conflicts.astype(jnp.int32)
     if base is None:
         base = jnp.zeros((w,), dtype=jnp.int32)

@@ -1,7 +1,8 @@
 """Public wrapper for wave-level assignment.
 
 Routes between the blocked Pallas kernel (compiled on TPU; interpreter
-elsewhere) and the reference ``lax.scan``. On CPU the scan is the default:
+elsewhere) and the reference ``lax.scan``, chosen when the call is traced
+(``repro.kernels.resolve``). On CPU the scan is the default:
 Pallas interpret mode re-traces the block loop in Python, while XLA
 compiles the scan into one tight loop. On TPU the blocked kernel replaces
 W dependent scan steps with W/B sequential grid steps whose operands stay
@@ -11,7 +12,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import ON_TPU
+from repro.kernels import resolve
 from repro.kernels.levels.levels import wave_levels_pallas
 from repro.kernels.levels.ref import wave_levels_ref
 from repro.obs.profiler import annotate
@@ -34,19 +35,17 @@ def wave_levels(conflicts, valid, *, base=None, backend: str | None = None,
 
     backend: None  — auto: Pallas (compiled) on TPU, the scan elsewhere;
              "pallas" — force the blocked kernel (interpret per
-                        ``interpret`` arg, itself auto-detected when None);
+                        ``interpret`` arg: None = compiled on TPU,
+                        interpreter elsewhere);
              "jnp"    — force the scan reference.
     """
     conflicts = jnp.asarray(conflicts)
     valid = jnp.asarray(valid, bool)
     if base is not None:
         base = jnp.asarray(base, jnp.int32)
-    if backend is None:
-        backend = "pallas" if ON_TPU else "jnp"
+    backend, interpret = resolve("wave_levels", backend, interpret)
     with annotate("protocol.wave_levels"):
         if backend == "jnp":
             return wave_levels_ref(conflicts, valid, base)
-        if backend == "pallas":
-            return wave_levels_pallas(conflicts, valid, base,
-                                      interpret=interpret)
-    raise ValueError(f"unknown levels backend {backend!r}")
+        return wave_levels_pallas(conflicts, valid, base,
+                                  interpret=interpret)

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.utils.compat import tpu_compiler_params
 
 DEFAULT_CHUNK = 128
 SUB = 32  # diagonal sub-block length
@@ -149,7 +148,7 @@ def wkv6_pallas(r, k, v, w, u, *, n_heads: int, interpret: bool = True,
             jax.ShapeDtypeStruct((bh, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

@@ -27,8 +27,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-from repro.utils.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.attention import attention_inner
@@ -92,7 +90,7 @@ def attn_mlp_block_sharded(lp, x, cfg, *, positions, window, mesh):
         return xs + mlp.astype(xs.dtype)
 
     kv_spec = P(None, "model") if kv_sharded else P(None, None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, "model", None),   # x: T-sharded
                   P(None),                   # norm1 scale

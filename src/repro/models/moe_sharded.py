@@ -30,8 +30,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-from repro.utils.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import swiglu
@@ -152,7 +150,7 @@ def moe_layer_sharded(params, x, cfg, mesh):
         aux = jnp.stack([lb, z, ov])
         return out.reshape(x_loc.shape[0], x_loc.shape[1], d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, None, None),
                   P(),                               # router replicated

@@ -204,10 +204,7 @@ def executor_cost(fn: Callable, *args, name: str = "executor"
     extract its compiled costs. Lowering never executes, so donated
     argument buffers are untouched."""
     compiled = fn.lower(*args).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict] on CPU
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = compiled.cost_analysis() or {}
     try:
         mem = compiled.memory_analysis()
     except Exception:  # pragma: no cover - backend without memory stats

@@ -314,6 +314,29 @@ def test_fit_tn_cost_model_recovery():
 # satellite contracts: non-finite stats rejected, timing carries samples
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the published table for the run's device kind; a
+    kind with no entry (a CPU or virtual-device BENCH) is an error."""
+    from benchmarks.roofline import device_peaks, mabs_roofline_rows
+
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["mem_bw"] == 819e9
+    assert "TPU v5e" in v5e["source"]
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("cpu")
+    row = {"kind": "engine", "model": "sis", "engine": "wavefront",
+           "window": 256, "n_devices": 1, "n_agents": 10**6,
+           "total_waves": 4, "seconds": 1e-3,
+           "cost": {"flops": 1e6, "bytes_accessed": 8.19e5}}
+    bench = {"meta": {"provenance": {"device_kind": "TPU v5 lite"}},
+             "rows": [row]}
+    (r,) = mabs_roofline_rows(bench)
+    assert r["t_memory_s"] == pytest.approx(4 * 8.19e5 / 819e9)
+    with pytest.raises(ValueError):
+        mabs_roofline_rows({"meta": {"provenance": {"device_kind": "cpu"}},
+                            "rows": [row]})
+
+
 def test_finalize_stats_rejects_nonfinite():
     from repro.obs import finalize_stats
 

@@ -17,7 +17,6 @@ from repro.distributed.sharding import (
     wave_halo_split,
     wave_slab_counts,
 )
-from repro.utils.compat import shard_map
 
 
 def _slab_rows(slabs, chunk_start, w):
@@ -95,7 +94,7 @@ def test_zero_width_gather_is_a_clean_noop():
         gc, slab = wave_halo_gather(loc, slabs0, jnp.int32(1), shard_n=6)
         return g, gc, slab
 
-    g, gc, slab = jax.jit(shard_map(
+    g, gc, slab = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(AGENT_AXIS),),
         out_specs=(P(), P(), P()), check_vma=False))(local)
     assert g.shape == (0, 2) and gc.shape == (0, 2) and slab.shape == (0,)
@@ -125,7 +124,7 @@ def test_gather_matches_monolithic_on_one_device():
         _, out = jax.lax.while_loop(lambda c: c[0] < c1, body, (c0, out))
         return out
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P(AGENT_AXIS),),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(AGENT_AXIS),),
                             out_specs=P(), check_vma=False))(state)
     expect = np.zeros(20, np.float32)
     for r in (3, 17, 11):   # wave 0's rows

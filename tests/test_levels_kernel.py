@@ -102,7 +102,7 @@ def test_levels_op_backends_and_default():
         out = wave_levels_op(conf, valid, backend=backend,
                              interpret=True)
         assert bool(jnp.all(out == ref))
-    # core.records.wave_levels is the auto-detect route execute_window uses
+    # core.records.wave_levels is the default route execute_window uses
     assert bool(jnp.all(wave_levels(jnp.asarray(conf),
                                     jnp.asarray(valid)) == ref))
     with pytest.raises(ValueError):
