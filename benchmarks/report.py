@@ -13,8 +13,8 @@ Four report families share this entry point:
     schedule's shape: wave-size histogram, critical-path length,
     per-device load imbalance and the comm-ledger breakdown per rung:
       PYTHONPATH=src python -m benchmarks.report explain TRACE.json
-  * Trace timing summary — where the traced run's wall time went
-    (schedule vs execute vs boundary, per-window table):
+  * Trace timing summary — where the traced run's host time went
+    (schedule vs execute vs boundary vs read-back, per-window table):
       PYTHONPATH=src python -m benchmarks.report trace TRACE.json
   * Benchmark regression compare — thresholded per-row verdicts between
     two BENCH_engine.json artifacts / ledger records (same schema);
@@ -299,7 +299,7 @@ def _bar(frac, width=30):
 
 
 def _trace_header(events):
-    runs = [e for e in events if e["name"] == "run" and e["ph"] == "B"]
+    runs = [e for e in events if e["name"] == "repro.run" and e["ph"] == "B"]
     if runs:
         a = runs[0].get("args", {})
         print(f"\nengine `{a.get('engine')}` · window {a.get('window')} · "
@@ -385,22 +385,23 @@ def bench_explain(bench, path):
 
 
 def trace_explain(path):
-    """Decode one protocol trace into the schedule's shape."""
+    """Decode one protocol trace into the schedule's shape, from the
+    per-wave lists each ``repro.execute`` span carries."""
     events = _load_trace(path)
     print(f"### Schedule explain — {os.path.basename(path)}")
     run_args = _trace_header(events)
-    waves = [e for e in events
-             if e.get("ph") == "X" and e["name"] == "wave"]
-    gathers = [e for e in events
-               if e.get("ph") == "X" and e["name"] == "halo_gather"]
-    if not waves:
-        print("\n(no wave spans in this trace — nothing to explain)")
+    execs = [e.get("args", {}) for e in events
+             if e.get("ph") == "B" and e["name"] == "repro.execute"
+             and "widths" in e.get("args", {})]
+    if not execs:
+        print("\n(no execute spans with per-wave widths in this trace — "
+              "nothing to explain)")
         return
 
     # ---- wave-size histogram (log2 buckets) + critical path
-    widths = [int(e["args"].get("width", 0)) for e in waves]
+    widths = [int(w) for a in execs for w in a["widths"]]
     total_tasks = sum(widths)
-    n_waves = len(waves)
+    n_waves = len(widths)
     print(f"\n#### Wave-size histogram ({n_waves} executed waves, "
           f"{total_tasks} tasks)\n")
     buckets: dict = {}
@@ -424,7 +425,7 @@ def trace_explain(path):
               f"upper bound {seq / max(n_waves, 1):.2f}×)")
 
     # ---- per-device load imbalance (sharded traces carry owned counts)
-    owned = [e["args"]["owned"] for e in waves if "owned" in e["args"]]
+    owned = [o for a in execs for o in a.get("owned", [])]
     if owned:
         d = len(owned[0])
         totals = [sum(o[i] for o in owned) for i in range(d)]
@@ -443,18 +444,20 @@ def trace_explain(path):
                   f"{sum(per_wave) / len(per_wave):.2f}×, "
                   f"worst {max(per_wave):.2f}×  (1.0× = perfectly even)")
 
-    # ---- comm-ledger breakdown per rung
-    if gathers:
-        print("\n#### Comm ledger (per-device receive volume, by "
-              "comm-ladder rung)\n")
-        rungs: dict = {}
-        for e in gathers:
-            a = e["args"]
+    # ---- comm-ledger breakdown per rung: the waves that shipped rows
+    rungs: dict = {}
+    for a in execs:
+        for rows, nbytes in zip(a.get("rows", []), a.get("bytes", [])):
+            if not rows:
+                continue
             r = rungs.setdefault(a.get("rung", "?"),
                                  {"waves": 0, "rows": 0, "bytes": 0})
             r["waves"] += 1
-            r["rows"] += int(a.get("rows", 0))
-            r["bytes"] += int(a.get("bytes", 0))
+            r["rows"] += int(rows)
+            r["bytes"] += int(nbytes)
+    if rungs:
+        print("\n#### Comm ledger (per-device receive volume, by "
+              "comm-ladder rung)\n")
         total_b = sum(r["bytes"] for r in rungs.values()) or 1
         print("| rung | waves | rows | bytes | share |")
         print("|---|---|---|---|---|")
@@ -466,11 +469,13 @@ def trace_explain(path):
             print(f"| {name} | {r['waves']} | {r['rows']:,} "
                   f"| {_fmt_kb(r['bytes'])} | `{_bar(frac)}` {frac:5.1%} |")
     else:
-        print("\n(no halo_gather spans — single-device trace, no comm)")
+        print("\n(no per-wave rows — single-device trace, no comm)")
 
 
 def trace_report(path):
-    """Where a traced run's wall time went (host-fenced span times)."""
+    """Where a traced run's host time went. Spans never fence: a
+    dispatch span holds the host's own work and any wait on the device
+    queue, the read-back span the wait for the device to finish."""
     events = _load_trace(path)
     print(f"### Trace timing — {os.path.basename(path)}")
     _trace_header(events)
@@ -478,49 +483,44 @@ def trace_report(path):
     if not spans:
         print("\n(no B/E spans in this trace)")
         return
-    run_dur = sum(d for n, _, d, _ in spans if n == "run") or 1.0
+    run_dur = sum(d for n, _, d, _ in spans if n == "repro.run") or 1.0
     by_name: dict = {}
     for name, _, dur, _ in spans:
-        if name == "run":
+        if name == "repro.run":
             continue
         c, t = by_name.get(name, (0, 0.0))
         by_name[name] = (c + 1, t + dur)
-    print("\n#### Phase totals (host wall time, fenced — tracing "
-          "serializes the window pipeline)\n")
-    print("| phase | spans | total ms | share of run |")
+    print("\n#### Phase totals (host time; unfenced spans)\n")
+    print("| phase | spans | total host ms | share of run |")
     print("|---|---|---|---|")
     for name, (c, t) in sorted(by_name.items(), key=lambda kv: -kv[1][1]):
         frac = t / run_dur
-        print(f"| {name} | {c} | {t / 1e3:.2f} "
+        print(f"| {name} | {c} | {t / 1e3:.3f} "
               f"| `{_bar(frac)}` {frac:5.1%} |")
-    # per-window schedule-vs-execute split
+    # per-window host split
+    phases = {"repro.schedule": "schedule", "repro.boundary": "boundary",
+              "repro.execute": "execute"}
     windows: dict = {}
     for name, _, dur, args in spans:
-        if name not in ("schedule", "execute", "boundary"):
+        if name not in phases:
             continue
         w = windows.setdefault(args.get("index", "?"),
                                {"n_waves": None, "rung": None})
-        w[name] = w.get(name, 0.0) + dur
-        if name == "execute":
+        w[phases[name]] = w.get(phases[name], 0.0) + dur
+        if name == "repro.execute":
             w["n_waves"] = args.get("n_waves")
             w["rung"] = args.get("rung")
     if windows:
-        print("\n#### Per-window split\n")
-        print("| window | schedule ms | boundary ms | execute ms "
-              "| waves | rung |")
+        print("\n#### Per-window split (host ms)\n")
+        print("| window | schedule host ms | boundary host ms "
+              "| execute host ms | waves | rung |")
         print("|---|---|---|---|---|---|")
         for i in sorted(windows, key=str):
             w = windows[i]
-            sch = w.get("schedule")
-            bnd = w.get("boundary")
-            exe = w.get("execute")
-            print(f"| {i} | {sch / 1e3:.2f}" if sch is not None
-                  else f"| {i} | —", end="")
-            print(f" | {bnd / 1e3:.2f}" if bnd is not None else " | —",
-                  end="")
-            print(f" | {exe / 1e3:.2f}" if exe is not None else " | —",
-                  end="")
-            print(f" | {w['n_waves'] if w['n_waves'] is not None else '—'} "
+            cells = [f"{w[k] / 1e3:.3f}" if k in w else "—"
+                     for k in ("schedule", "boundary", "execute")]
+            print(f"| {i} | {' | '.join(cells)} "
+                  f"| {w['n_waves'] if w['n_waves'] is not None else '—'} "
                   f"| {w['rung'] or '—'} |")
 
 

@@ -25,14 +25,13 @@ overlaps the wave execution of the current one on the device queue.
 from __future__ import annotations
 
 import abc
-from contextlib import nullcontext
 from typing import Any, Type
 
 import jax
 import jax.numpy as jnp
 
 from repro.obs.stats import finalize_stats
-from repro.obs.trace import TID_COMM, current_tracer
+from repro.obs.trace import current_tracer, optional_span
 
 ENGINES: dict[str, Type["Engine"]] = {}
 
@@ -201,80 +200,68 @@ class WindowedEngine(Engine):
 
     # ------------------------------------------------------------- tracing
     #
-    # Every hook below is reached only when a tracer is installed
-    # (``repro.obs.trace.tracing()``); the untraced run loops guard on a
-    # single ``current_tracer() is None`` check, so tracing off adds zero
-    # host syncs to the hot path. With tracing on, span boundaries fence
-    # with ``jax.block_until_ready`` — which deliberately serializes the
-    # double-buffered window pipeline to attribute wall time to the
-    # schedule vs execute halves (docs/observability.md).
+    # Reached only with a tracer installed (``repro.obs.tracing()``); the
+    # run loops check ``current_tracer()`` once per run, so the untraced
+    # loops hold no trace call. Spans never fence: a traced run issues the
+    # same jitted calls in the same order as an untraced one, and what
+    # only the device knows (wave counts, levels, shipped rows) is kept by
+    # reference on the span and read after the run (``Span.defer``).
 
-    def _trace_parts(self, sched, levels=None):
+    def _trace_parts(self, sched, levels=None):  # pragma: no cover - abstract
         """(levels, write_agents, rows) of one window's schedule, for the
-        per-wave trace attributes. ``levels`` overrides the schedule's
-        own level vector (the overlapped loop re-levels and rebases).
-        None disables per-wave spans for this engine."""
+        per-wave facts. ``levels`` overrides the schedule's own level
+        vector (the overlapped loop re-levels and rebases)."""
+        raise NotImplementedError
+
+    def _trace_comm(self):
+        """The comm-ledger entry of the window just executed, or None for
+        engines that ship nothing (single device)."""
         return None
 
-    def _trace_wave_comm(self, np_parts, n_waves):
-        """Per-wave comm attributes (list of dicts with ``rung``/``rows``
-        /``bytes`` and optionally ``owned`` per-device task counts), or
-        None for engines that ship nothing (single device)."""
-        return None
-
-    def _trace_execute_args(self):
-        """Extra args for a just-closed execute span (e.g. the sharded
-        engine's comm-ladder rung)."""
+    def _trace_wave_comm(self, np_parts, n_waves, entry):
+        """Comm facts of one executed window from its ledger ``entry``:
+        a dict of per-execute args (e.g. the sharded engine's rung and
+        per-wave ``rows``/``bytes``/``owned`` lists); empty when
+        ``entry`` is None."""
         return {}
 
-    def _dispatch_schedule(self, tr, base_key, start, count, *, index,
-                           ov=False):
-        """Dispatch one window's schedule, wrapped in a fenced span when
-        tracing is on."""
-        fn = self._schedule_ov if ov else self._schedule
+    def _stats_syncs(self) -> int:
+        """Device values ``_extend_stats`` reads, each a blocking read."""
+        return 0
+
+    def _dispatch_schedule(self, tr, fn, base_key, start, count, *, index):
+        """Dispatch one window's schedule (``fn``), inside a
+        ``repro.schedule`` span when tracing is on."""
         if tr is None:
             return fn(base_key, start, count)
-        with tr.span("schedule", index=index, start=start, count=count):
-            sched = fn(base_key, start, count)
-            jax.block_until_ready(sched)
-        return sched
+        with tr.span("repro.schedule", index=index, start=start, count=count):
+            return fn(base_key, start, count)
 
-    def _trace_window(self, tr, sp, parts, n_waves):
-        """Emit per-wave spans (width-proportional attribution inside the
-        closed execute span ``sp``) and per-wave ``halo_gather`` spans.
-        ``parts`` holds one ``_trace_parts`` triple per live window (two
-        for a fused pair drain)."""
+    def _traced_execute(self, tr, fn, args, parts, **span_args):
+        """Dispatch one window's executor ``fn(*args)`` inside a
+        ``repro.execute`` span, whose per-wave facts are read after the
+        run from ``parts`` (the live windows' ``_trace_parts``: two for a
+        fused pair drain) and the executor's wave count."""
+        with tr.span("repro.execute", **span_args) as sp:
+            out = fn(*args)
+        sp.defer(self._wave_facts, parts, out[1], self._trace_comm())
+        return out
+
+    def _wave_facts(self, parts, n_waves, entry):
         import numpy as np
 
-        parts = [p for p in parts if p is not None]
-        if n_waves <= 0 or not parts:
-            return
-        widths = np.zeros(n_waves, np.int64)
+        n = int(n_waves)
+        widths = np.zeros(n, np.int64)
         np_parts = []
         for lv, wa, rows in parts:
             lv = np.asarray(lv)
             np_parts.append((lv,
                              None if wa is None else np.asarray(wa),
                              None if rows is None else np.asarray(rows)))
-            sel = lv[(lv >= 0) & (lv < n_waves)]
-            if sel.size:
-                widths[:] += np.bincount(sel, minlength=n_waves)[:n_waves]
-        comm = self._trace_wave_comm(np_parts, n_waves)
-        window = sp.args.get("index")
-        args = [{"window": window, "level": w, "width": int(widths[w])}
-                for w in range(n_waves)]
-        if comm is not None:
-            for a, c in zip(args, comm):
-                owned = c.pop("owned", None)
-                if owned is not None:
-                    a["owned"] = owned
-        slots = tr.subdivide(sp, "wave", widths.tolist(), args)
-        if comm is not None:
-            for w, ((ts, dur), c) in enumerate(zip(slots, comm)):
-                if c.get("rows"):
-                    tr.complete("halo_gather", ts, dur, tid=TID_COMM,
-                                window=window, level=w, attributed=True,
-                                **c)
+            sel = lv[(lv >= 0) & (lv < n)]
+            widths += np.bincount(sel, minlength=n)[:n]
+        return {"n_waves": n, "widths": widths.tolist(),
+                **self._trace_wave_comm(np_parts, n, entry)}
 
     # ------------------------------------------------- cross-window overlap
     def _make_boundary(self):
@@ -313,33 +300,32 @@ class WindowedEngine(Engine):
 
         return boundary
 
-    def _levels0(self, conf, valid):
-        """First window's levels (no predecessor -> no carry floor)."""
+    def _schedule_first(self, base_key, start, count):
+        """Overlap mode's first window: its schedule and its levels (no
+        predecessor, so no carry floor)."""
         if getattr(self, "_levels0_fn", None) is None:
             from repro.core.records import wave_levels
 
-            self._levels0_fn = jax.jit(
-                lambda c, v: wave_levels(c, v))
-        return self._levels0_fn(conf, valid)
+            self._levels0_fn = jax.jit(wave_levels)
+        cur = self._schedule_ov(base_key, start, count)
+        return cur, self._levels0_fn(cur[2], cur[1])
 
     def _run_overlapped(self, state: Any, total_tasks: int, *, seed: int = 0):
         tr = current_tracer()
-        base_key = jax.random.key(seed)
-        state = self._prepare_state(state)
-        if getattr(self, "_boundary_fn", None) is None:
-            self._boundary_fn = self._make_boundary()
         t = 0
         n_windows = 0
         wave_counts = []
         bstats = []
-        run_cm = (tr.span("run", engine=self.name, window=self.window,
-                          total_tasks=total_tasks, overlap=True)
-                  if tr is not None else nullcontext())
-        with run_cm:
-            cur = self._dispatch_schedule(
-                tr, base_key, 0, min(self.window, total_tasks),
-                index=0, ov=True)
-            lv = self._levels0(cur[2], cur[1])
+        with optional_span(tr, "repro.run", engine=self.name,
+                           window=self.window, total_tasks=total_tasks,
+                           overlap=True):
+            base_key = jax.random.key(seed)
+            state = self._prepare_state(state)
+            if getattr(self, "_boundary_fn", None) is None:
+                self._boundary_fn = self._make_boundary()
+            cur, lv = self._dispatch_schedule(
+                tr, self._schedule_first, base_key, 0,
+                min(self.window, total_tasks), index=0)
             while t < total_tasks:
                 k = min(self.window, total_tasks - t)
                 if t + k < total_tasks:
@@ -349,38 +335,27 @@ class WindowedEngine(Engine):
                     # double buffering as the barrier loop, now with the
                     # carry-over record check
                     nxt = self._dispatch_schedule(
-                        tr, base_key, t + k,
+                        tr, self._schedule_ov, base_key, t + k,
                         min(self.window, total_tasks - t - k),
-                        index=n_windows + 1, ov=True)
+                        index=n_windows + 1)
                     if tr is None:
                         lv_nxt, b = self._boundary_fn(cur[0], lv,
                                                       nxt[0], nxt[1], nxt[2])
-                    else:
-                        with tr.span("boundary", index=n_windows) as bsp:
-                            lv_nxt, b = self._boundary_fn(
-                                cur[0], lv, nxt[0], nxt[1], nxt[2])
-                            jax.block_until_ready((lv_nxt, b))
-                        bsp.args.update(
-                            overlap_depth=int(b[0]), early_tasks=int(b[1]),
-                            carry_mean=float(b[2]), carry_max=int(b[3]))
-                    bstats.append(b)
-                    if tr is None:
                         state, n_waves, lv_nxt = self._execute_pair(
                             state, cur, lv, nxt, lv_nxt)
                     else:
-                        lv_pre = lv_nxt  # pre-rebase levels: wave widths
-                        with tr.span("execute", index=n_windows, start=t,
-                                     count=k, fused=True) as sp:
-                            state, n_waves, lv_nxt = self._execute_pair(
-                                state, cur, lv, nxt, lv_nxt)
-                            jax.block_until_ready(state)
-                            n_waves = int(n_waves)
-                        sp.args["n_waves"] = n_waves
-                        sp.args.update(self._trace_execute_args())
-                        self._trace_window(
-                            tr, sp, [self._trace_parts(cur, lv),
-                                     self._trace_parts(nxt, lv_pre)],
-                            n_waves)
+                        with tr.span("repro.boundary",
+                                     index=n_windows) as bsp:
+                            lv_nxt, b = self._boundary_fn(
+                                cur[0], lv, nxt[0], nxt[1], nxt[2])
+                        bsp.defer(_boundary_facts, b)
+                        state, n_waves, lv_nxt = self._traced_execute(
+                            tr, self._execute_pair,
+                            (state, cur, lv, nxt, lv_nxt),
+                            [self._trace_parts(cur, lv),
+                             self._trace_parts(nxt, lv_nxt)],
+                            index=n_windows, start=t, count=k, fused=True)
+                    bstats.append(b)
                     cur, lv = nxt, lv_nxt
                 else:
                     # last window: no partner — drain through the barrier
@@ -390,41 +365,41 @@ class WindowedEngine(Engine):
                     if tr is None:
                         state, n_waves = self._execute_drain(state, cur, lv)
                     else:
-                        with tr.span("execute", index=n_windows, start=t,
-                                     count=k, drain=True) as sp:
-                            state, n_waves = self._execute_drain(
-                                state, cur, lv)
-                            jax.block_until_ready(state)
-                            n_waves = int(n_waves)
-                        sp.args["n_waves"] = n_waves
-                        sp.args.update(self._trace_execute_args())
-                        self._trace_window(
-                            tr, sp, [self._trace_parts(cur, lv)], n_waves)
+                        state, n_waves = self._traced_execute(
+                            tr, self._execute_drain, (state, cur, lv),
+                            [self._trace_parts(cur, lv)],
+                            index=n_windows, start=t, count=k, drain=True)
                 wave_counts.append(n_waves)
                 n_windows += 1
                 t += k
-        total_waves = int(sum(int(w) for w in wave_counts))  # host sync here
-        state = self._finalize_state(state)
-        depths = [int(b[0]) for b in bstats]
-        earlies = [int(b[1]) for b in bstats]
-        cmeans = [float(b[2]) for b in bstats]
-        cmaxs = [int(b[3]) for b in bstats]
-        stats = {
-            "total_tasks": total_tasks,
-            "n_windows": n_windows,
-            "total_waves": total_waves,
-            "mean_parallelism": total_tasks / max(total_waves, 1),
-            "overlap": True,
-            "n_boundaries": len(bstats),
-            "mean_overlap_depth": (sum(depths) / len(depths)
-                                   if depths else 0.0),
-            "max_overlap_depth": max(depths, default=0),
-            "overlap_tasks_early": sum(earlies),
-            "carry_frontier_mean": (sum(cmeans) / len(cmeans)
-                                    if cmeans else 0.0),
-            "carry_frontier_max": max(cmaxs, default=0),
-        }
-        return state, finalize_stats(self._extend_stats(stats))
+            with optional_span(tr, "repro.readback", windows=n_windows):
+                # the run's host syncs: one read a window, four a boundary
+                total_waves = int(sum(int(w) for w in wave_counts))
+                state = self._finalize_state(state)
+                depths = [int(b[0]) for b in bstats]
+                earlies = [int(b[1]) for b in bstats]
+                cmeans = [float(b[2]) for b in bstats]
+                cmaxs = [int(b[3]) for b in bstats]
+                stats = {
+                    "total_tasks": total_tasks,
+                    "n_windows": n_windows,
+                    "total_waves": total_waves,
+                    "mean_parallelism": total_tasks / max(total_waves, 1),
+                    "overlap": True,
+                    "n_boundaries": len(bstats),
+                    "mean_overlap_depth": (sum(depths) / len(depths)
+                                           if depths else 0.0),
+                    "max_overlap_depth": max(depths, default=0),
+                    "overlap_tasks_early": sum(earlies),
+                    "carry_frontier_mean": (sum(cmeans) / len(cmeans)
+                                            if cmeans else 0.0),
+                    "carry_frontier_max": max(cmaxs, default=0),
+                }
+                stats = finalize_stats(self._extend_stats(stats))
+            if tr is not None:
+                tr.count("host_syncs", n_windows + 4 * len(bstats)
+                         + self._stats_syncs())
+        return state, stats
 
     def run(self, state: Any, total_tasks: int, *, seed: int = 0):
         if self.overlap:
@@ -437,17 +412,17 @@ class WindowedEngine(Engine):
                     "overlap; use overlap=False (the barrier fallback)")
             return self._run_overlapped(state, total_tasks, seed=seed)
         tr = current_tracer()
-        base_key = jax.random.key(seed)
-        state = self._prepare_state(state)
         t = 0
         n_windows = 0
         wave_counts = []
-        run_cm = (tr.span("run", engine=self.name, window=self.window,
-                          total_tasks=total_tasks, overlap=False)
-                  if tr is not None else nullcontext())
-        with run_cm:
+        with optional_span(tr, "repro.run", engine=self.name,
+                           window=self.window, total_tasks=total_tasks,
+                           overlap=False):
+            base_key = jax.random.key(seed)
+            state = self._prepare_state(state)
             nxt = self._dispatch_schedule(
-                tr, base_key, 0, min(self.window, total_tasks), index=0)
+                tr, self._schedule, base_key, 0,
+                min(self.window, total_tasks), index=0)
             while t < total_tasks:
                 k = min(self.window, total_tasks - t)
                 cur = nxt
@@ -456,34 +431,40 @@ class WindowedEngine(Engine):
                     # (conflict matrix + levels) before blocking on window
                     # t's execution
                     nxt = self._dispatch_schedule(
-                        tr, base_key, t + k,
+                        tr, self._schedule, base_key, t + k,
                         min(self.window, total_tasks - t - k),
                         index=n_windows + 1)
                 if tr is None:
                     state, n_waves = self._execute(state, cur)
                 else:
-                    with tr.span("execute", index=n_windows, start=t,
-                                 count=k) as sp:
-                        state, n_waves = self._execute(state, cur)
-                        jax.block_until_ready(state)
-                        n_waves = int(n_waves)
-                    sp.args["n_waves"] = n_waves
-                    sp.args.update(self._trace_execute_args())
-                    self._trace_window(
-                        tr, sp, [self._trace_parts(cur)], n_waves)
+                    state, n_waves = self._traced_execute(
+                        tr, self._execute, (state, cur),
+                        [self._trace_parts(cur)],
+                        index=n_windows, start=t, count=k)
                 wave_counts.append(n_waves)
                 n_windows += 1
                 t += k
-        total_waves = int(sum(int(w) for w in wave_counts))  # host sync here
-        state = self._finalize_state(state)
-        stats = {
-            "total_tasks": total_tasks,
-            "n_windows": n_windows,
-            "total_waves": total_waves,
-            "mean_parallelism": total_tasks / max(total_waves, 1),
-            "overlap": False,
-        }
-        return state, finalize_stats(self._extend_stats(stats))
+            with optional_span(tr, "repro.readback", windows=n_windows):
+                # the run's host syncs: one read a window
+                total_waves = int(sum(int(w) for w in wave_counts))
+                state = self._finalize_state(state)
+                stats = {
+                    "total_tasks": total_tasks,
+                    "n_windows": n_windows,
+                    "total_waves": total_waves,
+                    "mean_parallelism": total_tasks / max(total_waves, 1),
+                    "overlap": False,
+                }
+                stats = finalize_stats(self._extend_stats(stats))
+            if tr is not None:
+                tr.count("host_syncs", n_windows + self._stats_syncs())
+        return state, stats
 
     def _extend_stats(self, stats: dict) -> dict:
         return stats
+
+
+def _boundary_facts(b):
+    """A boundary span's overlap accounting, read after the run."""
+    return {"overlap_depth": int(b[0]), "early_tasks": int(b[1]),
+            "carry_mean": float(b[2]), "carry_max": int(b[3])}

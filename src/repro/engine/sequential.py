@@ -12,7 +12,7 @@ import jax
 
 from repro.engine.base import Engine, register_engine
 from repro.obs.stats import finalize_stats
-from repro.obs.trace import current_tracer
+from repro.obs.trace import current_tracer, optional_span
 
 
 def run_sequential(model, state, total_tasks: int, *, seed: int = 0,
@@ -32,10 +32,9 @@ def run_sequential(model, state, total_tasks: int, *, seed: int = 0,
         if tr is None:
             state = seq(state, base_key, t, k)
         else:
-            with tr.span("execute", index=index, start=t, count=k,
+            with tr.span("repro.execute", index=index, start=t, count=k,
                          sequential=True):
                 state = seq(state, base_key, t, k)
-                jax.block_until_ready(state)
         t += k
         index += 1
     return state
@@ -49,13 +48,9 @@ class SequentialEngine(Engine):
     name = "sequential"
 
     def run(self, state: Any, total_tasks: int, *, seed: int = 0):
-        from contextlib import nullcontext
-
-        tr = current_tracer()
-        run_cm = (tr.span("run", engine=self.name, window=self.window,
-                          total_tasks=total_tasks, overlap=False)
-                  if tr is not None else nullcontext())
-        with run_cm:
+        with optional_span(current_tracer(), "repro.run", engine=self.name,
+                           window=self.window, total_tasks=total_tasks,
+                           overlap=False):
             state = run_sequential(self.model, state, total_tasks,
                                    seed=seed, window=self.window)
         stats = {

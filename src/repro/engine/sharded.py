@@ -589,10 +589,10 @@ class ShardedEngine(WindowedEngine):
         return {1: int(stats["total_waves"]), 2: chunk_iters}
 
     # ------------------------------------------------------------ tracing
-    # Reached only with a tracer installed (repro.obs) — the comm ledger
-    # entry appended by the window's executor names the rung, and the
+    # Reached only with a tracer installed (repro.obs). The ledger entry
+    # the window's executor appended names the rung; after the run, the
     # schedule's replicated level/row/write-target arrays reproduce the
-    # per-wave shipped volume host-side (the split math below mirrors
+    # per-wave shipped volume on the host (the split math below mirrors
     # ``wave_halo_split``: valid row slots per wave, ceil'd to chunks).
 
     _RUNG_NAMES = {"split": "split", "halo": "window_halo",
@@ -606,19 +606,20 @@ class ShardedEngine(WindowedEngine):
             wa, _, rows = sched[3]
         return lv, wa, rows
 
-    def _trace_execute_args(self):
-        if not self._win_comm:
-            return {}
-        kind, _, _ = self._win_comm[-1]
-        return {"rung": self._RUNG_NAMES[kind], "n_devices": self.n_devices}
+    def _trace_comm(self):
+        return self._win_comm[-1] if self._win_comm else None
 
-    def _trace_wave_comm(self, np_parts, n_waves):
+    def _stats_syncs(self) -> int:
+        # the split entries' shipped rows; every entry's wave count is
+        # the run's own, already read
+        return sum(isinstance(r, jax.Array) for _, r, _ in self._win_comm)
+
+    def _trace_wave_comm(self, np_parts, n_waves, entry):
         import numpy as np
 
-        if not self._win_comm:
-            return None
-        kind = self._win_comm[-1][0]
-        rung = self._RUNG_NAMES[kind]
+        if entry is None:
+            return {}
+        kind = entry[0]
         if kind == "split":
             per_wave = np.zeros(n_waves, np.int64)
             for lv, _, rows in np_parts:
@@ -642,10 +643,10 @@ class ShardedEngine(WindowedEngine):
             for i in np.nonzero((lv >= 0) & (lv < n_waves))[0]:
                 devs = np.unique(dev[i])
                 owned[lv[i], devs[devs >= 0]] += 1
-        rb = self._row_bytes
-        return [{"rung": rung, "rows": int(r), "bytes": int(r) * rb,
-                 "owned": owned[w].tolist()}
-                for w, r in enumerate(per_wave)]
+        return {"rung": self._RUNG_NAMES[kind], "n_devices": self.n_devices,
+                "rows": per_wave.tolist(),
+                "bytes": (per_wave * self._row_bytes).tolist(),
+                "owned": owned.tolist()}
 
 
 @register_engine

@@ -4,12 +4,15 @@ The paper's central claim — adaptive, graceful handling of heterogeneous
 computation — is only testable if the protocol can be *measured from the
 inside*. This package is that layer:
 
-  trace.py      — structured span tracer: per-window, per-wave and
-                  per-boundary events (wave width, level, halo rows/bytes
-                  per comm-ladder rung, overlap depth, schedule-vs-execute
-                  split), exported as Chrome trace-event JSON (Perfetto-
-                  loadable). Off by default; the engines' hot path adds
-                  **zero** host syncs when no tracer is installed.
+  trace.py      — span tracer of the engines' run loops: ``repro.run``,
+                  ``repro.schedule``, ``repro.boundary``,
+                  ``repro.execute`` and ``repro.readback`` spans and the
+                  ``host_syncs`` counter. Spans never fence; each is also
+                  a ``jax.profiler.TraceAnnotation``, on the profiler's
+                  clock; per-wave facts (widths, halo rows/bytes per
+                  comm-ladder rung, owned tasks) are read after the run.
+                  Exported as Chrome trace-event JSON (Perfetto-
+                  loadable). Off by default, and no cost when off.
   stats.py      — typed, versioned stats registry: every engine stat is
                   declared once (type, group, docstring); engine ``run``
                   stats are validated against it and normalized to
@@ -26,7 +29,7 @@ inside*. This package is that layer:
                   collective traffic of the jitted window executors,
                   cross-checked against the runtime comm ledger.
 
-See docs/observability.md for the span taxonomy and report walkthrough.
+See docs/observability.md for the spans, the counter and the reports.
 """
 from repro.obs.costs import (
     CrossCheck,

@@ -1,9 +1,11 @@
 """Device-profile integration for the protocol phases.
 
-Two complementary timing sources exist (docs/observability.md):
+Two timing sources meet in one profile (docs/observability.md):
 
-  * the host span tracer (obs/trace.py) — wall-clock structure per
-    window/wave/boundary, fenced by ``block_until_ready``;
+  * the span tracer (obs/trace.py) — unfenced host spans of the run
+    loops (``repro.schedule``, ``repro.execute``, ...), each entered as
+    a ``jax.profiler.TraceAnnotation``, so under a profiler session they
+    land on the host plane, on the device ops' clock;
   * the XLA device profiler (``jax.profiler.trace``) — op-accurate
     device timelines, where the protocol phases show up by name because
     the scheduling kernels, halo gathers and window executors are

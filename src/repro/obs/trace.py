@@ -1,41 +1,44 @@
-"""Structured span tracer for the wavefront protocol.
+"""Span tracer of the engines' run loops.
 
-Records *where wall-clock time goes* per window, wave and boundary, and
-exports Chrome trace-event JSON (the ``{"traceEvents": [...]}`` format
-Perfetto / ``chrome://tracing`` load directly).
+Records what the host does in each ``Engine.run``: one span for the run,
+for each window's schedule dispatch, each overlap boundary, each window's
+execute dispatch and the read-back of the run's statistics, plus
+counters (``host_syncs``: the blocking device-to-host reads of a run).
+Exports Chrome trace-event JSON (``{"traceEvents": [...]}``, which
+Perfetto and ``chrome://tracing`` load).
 
-Design constraints (docs/observability.md):
+Design (docs/observability.md):
 
-* **Off by default, zero hot-path cost.** No tracer is installed unless
-  the caller enters ``tracing()``; the engines guard every trace call
-  with a single ``current_tracer() is None`` check, so the untraced hot
-  path gains no host syncs, no allocations, no branches inside jit.
-* **Fenced host timestamps.** With tracing on, span boundaries call
-  ``jax.block_until_ready`` on the span's outputs, so a span's duration
-  is real device+host wall time, not async-dispatch time. This
-  deliberately serializes the double-buffered window pipeline — tracing
-  trades throughput for attribution (the schedule-vs-execute split is
-  exactly what the pipeline hides).
-* **Honest per-wave timing.** Waves execute inside a fused
-  ``lax.while_loop``; the host cannot observe individual iterations. Per
-  -wave spans are therefore *attributed*: the measured window-execute
-  span is subdivided proportionally to wave width, and each wave span
-  carries ``"attributed": true`` plus its real schedule-derived
-  attributes (level, width, halo rows/bytes per comm-ladder rung, per-
-  device owned-task counts). Device-accurate per-phase timing comes from
-  ``jax.profiler.trace`` + the ``protocol.*`` named scopes instead
-  (obs/profiler.py).
+* **Off by default, no cost when off.** No tracer is installed unless
+  the caller enters ``tracing()``; the engines check
+  ``current_tracer() is None`` once per run and take the untraced path,
+  which has no trace call, allocation or branch inside its loops.
+* **No fences.** A span never waits for the device: a traced run issues
+  the same jitted calls in the same order as an untraced one, with no
+  extra host sync. A span's time is the host's time in its block (the
+  dispatch of a jitted call, its argument puts, or a read-back that
+  blocks on the device queue).
+* **One clock.** Every span is also entered as
+  ``jax.profiler.TraceAnnotation(name)``, so under a profiler session it
+  lands on the XSpace host plane beside JAX's own events, on the clock of
+  the device ops. The in-memory copy stamps ``time.time_ns()``, the clock
+  the profiler's host events use (tests/test_obs.py pins the agreement).
+* **Deferred facts.** What only the device knows (a window's wave count,
+  levels, shipped rows) is kept by reference on its span
+  (``Span.defer``) and read when the trace is rendered (``events``),
+  after the run.
 
-Span taxonomy (all under pid 1, process "repro.protocol"):
+Spans (pid 1, process "repro.protocol", one lane; all nest under
+``repro.run``):
 
-  tid 0 "windows"  — B/E spans: ``run`` (whole engine run), ``schedule``
-                     (one window's conflict+levels dispatch), ``execute``
-                     (one window's wave drain), ``boundary`` (overlap
-                     carry step: cross block + frontier + re-level).
-  tid 1 "waves"    — X spans: one ``wave`` per executed (fused) wave,
-                     width-proportional attribution inside its window.
-  tid 2 "comm"     — X spans: one ``halo_gather`` per wave that shipped
-                     rows, with ``rung``/``rows``/``bytes`` attributes.
+  repro.run       one ``Engine.run``
+  repro.schedule  one window's schedule dispatch (scalar puts included)
+  repro.boundary  one overlap boundary step
+  repro.execute   one window's execute dispatch; after the run it holds
+                  ``n_waves`` and ``widths`` (tasks per wave), and on the
+                  sharded engines ``rung``, ``rows``, ``bytes`` and
+                  ``owned`` (tasks per device) per wave
+  repro.readback  from the last dispatch to the stats dict
 
 Usage:
 
@@ -44,44 +47,64 @@ Usage:
     with tracing() as tr:
         state, stats = engine.run(state, total)
     tr.export("trace.json")           # -> load in ui.perfetto.dev
+    tr.counters["host_syncs"]
 """
 from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any
 
-#: Chrome trace-event phases the tracer emits / the validator accepts.
+import jax
+
+#: Chrome trace-event phases the validator accepts.
 PHASES = frozenset({"B", "E", "X", "i", "I", "C", "M"})
 
 PID = 1
-TID_WINDOWS = 0
-TID_WAVES = 1
-TID_COMM = 2
-
-_THREAD_NAMES = {TID_WINDOWS: "windows", TID_WAVES: "waves",
-                 TID_COMM: "comm"}
+TID = 0
 
 
 class Span:
-    """An open (or closed) B/E span; ``args`` may be extended until
-    export — the engines attach outputs that only exist after the fence
-    (e.g. the executed wave count) to an already-entered span."""
+    """One span: a context manager that stamps its host times (ns on
+    ``time.time_ns``'s clock) and enters a profiler annotation of the same
+    name. ``args`` may be extended until export."""
 
-    __slots__ = ("name", "cat", "tid", "args", "t0", "t1")
+    __slots__ = ("name", "args", "t0", "t1", "_ann", "_later")
 
-    def __init__(self, name: str, cat: str, tid: int, args: dict):
+    def __init__(self, name: str, args: dict):
         self.name = name
-        self.cat = cat
-        self.tid = tid
         self.args = args
-        self.t0: float = 0.0
-        self.t1: float | None = None
+        self.t0: int = 0
+        self.t1: int | None = None
+        self._ann = None
+        self._later: list = []
+
+    def __enter__(self):
+        self.t0 = time.time_ns()
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self.t1 = time.time_ns()
+        self._ann = None
+        return False
+
+    def defer(self, fn, *refs) -> None:
+        """Add ``fn(*refs)`` (a dict) to ``args`` when the trace is
+        rendered: ``refs`` are device values the run must not wait for."""
+        self._later.append((fn, refs))
+
+    def resolve(self) -> None:
+        while self._later:
+            fn, refs = self._later.pop(0)
+            self.args.update(fn(*refs))
 
 
 class SpanTracer:
-    """Collects trace events in memory; export renders Chrome JSON.
+    """Spans and counters of the runs made while it is installed.
 
     Not thread-safe by design: the engines' run loops are single-
     threaded hosts, and the tracer is installed per ``tracing()`` block.
@@ -89,89 +112,34 @@ class SpanTracer:
 
     def __init__(self, *, process_name: str = "repro.protocol"):
         self.process_name = process_name
-        self._spans: list[Span] = []          # closed + open B/E spans
-        self._events: list[dict] = []         # X / i / C events
-        self._stack: list[Span] = []          # open spans (tid 0 only)
-        self._epoch = time.perf_counter()
+        self.spans: list[Span] = []
+        self.counters: dict[str, int] = {}
 
-    # ------------------------------------------------------------ clock
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._epoch) * 1e6
+    def span(self, name: str, **args: Any) -> Span:
+        """A span around a block (``with tr.span(name, **args) as sp``)."""
+        sp = Span(name, args)
+        self.spans.append(sp)
+        return sp
 
-    # ------------------------------------------------------------ spans
-    @contextmanager
-    def span(self, name: str, *, cat: str = "protocol",
-             tid: int = TID_WINDOWS, **args: Any):
-        """B/E span around a block. The yielded ``Span`` exposes ``args``
-        (mutable until export) and, after exit, ``t0``/``t1`` in µs —
-        ``subdivide`` uses them to attribute child wave spans. The caller
-        is responsible for fencing device work inside the block (the
-        engines call ``jax.block_until_ready`` before exiting) so the
-        recorded duration is real wall time."""
-        sp = Span(name, cat, tid, dict(args))
-        sp.t0 = self._now_us()
-        self._stack.append(sp)
-        self._spans.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t1 = self._now_us()
-            self._stack.pop()
-
-    def instant(self, name: str, *, cat: str = "protocol",
-                tid: int = TID_WINDOWS, **args: Any) -> None:
-        self._events.append({"name": name, "ph": "i", "cat": cat,
-                             "ts": self._now_us(), "pid": PID, "tid": tid,
-                             "s": "t", "args": args})
-
-    def complete(self, name: str, ts_us: float, dur_us: float, *,
-                 cat: str = "protocol", tid: int = TID_WAVES,
-                 **args: Any) -> None:
-        """X (complete) event with explicit timestamps."""
-        self._events.append({"name": name, "ph": "X", "cat": cat,
-                             "ts": float(ts_us), "dur": float(dur_us),
-                             "pid": PID, "tid": tid, "args": args})
-
-    def subdivide(self, parent: Span, name: str, weights, args_list, *,
-                  tid: int = TID_WAVES, cat: str = "protocol",
-                  ) -> list[tuple[float, float]]:
-        """Attribute ``parent``'s measured duration to child X spans in
-        proportion to ``weights`` (the engines pass wave widths — see the
-        module docstring for why per-wave timing is attribution, not
-        measurement). ``args_list[i]`` extends child i's args. Returns
-        the children's (ts, dur) slots so the caller can align further
-        events (e.g. per-wave halo-gather spans) with them."""
-        assert parent.t1 is not None, "subdivide() needs a closed span"
-        total = float(sum(weights)) or 1.0
-        dur = parent.t1 - parent.t0
-        t = parent.t0
-        slots: list[tuple[float, float]] = []
-        for i, (wgt, extra) in enumerate(zip(weights, args_list)):
-            d = dur * float(wgt) / total
-            self.complete(name, t, d, tid=tid, cat=cat,
-                          index=i, attributed=True, **extra)
-            slots.append((t, d))
-            t += d
-        return slots
+    def count(self, name: str, n: int = 1) -> None:
+        self.counters[name] = self.counters.get(name, 0) + n
 
     # ----------------------------------------------------------- export
     def events(self) -> list[dict]:
-        """Render every recorded event as a Chrome trace-event dict."""
+        """Every span as a Chrome B/E event pair (``ts`` in µs on the
+        profiler's clock), its deferred facts read first."""
         out: list[dict] = [
-            {"name": "process_name", "ph": "M", "pid": PID, "tid": 0,
+            {"name": "process_name", "ph": "M", "pid": PID, "tid": TID,
              "args": {"name": self.process_name}},
         ]
-        for tid, tname in _THREAD_NAMES.items():
-            out.append({"name": "thread_name", "ph": "M", "pid": PID,
-                        "tid": tid, "args": {"name": tname}})
-        for sp in self._spans:
-            out.append({"name": sp.name, "ph": "B", "cat": sp.cat,
-                        "ts": sp.t0, "pid": PID, "tid": sp.tid,
+        for sp in self.spans:
+            sp.resolve()
+            t1 = sp.t1 if sp.t1 is not None else time.time_ns()
+            out.append({"name": sp.name, "ph": "B", "cat": "protocol",
+                        "ts": sp.t0 / 1e3, "pid": PID, "tid": TID,
                         "args": dict(sp.args)})
-            out.append({"name": sp.name, "ph": "E", "cat": sp.cat,
-                        "ts": sp.t1 if sp.t1 is not None else self._now_us(),
-                        "pid": PID, "tid": sp.tid})
-        out.extend(self._events)
+            out.append({"name": sp.name, "ph": "E", "cat": "protocol",
+                        "ts": t1 / 1e3, "pid": PID, "tid": TID})
         # stable ts order (ties keep emission order, so an E at the same
         # timestamp as the next B stays correctly nested)
         out.sort(key=lambda e: e.get("ts", 0.0))
@@ -179,7 +147,8 @@ class SpanTracer:
 
     def to_chrome_trace(self) -> dict:
         return {"traceEvents": self.events(), "displayTimeUnit": "ms",
-                "otherData": {"tracer": "repro.obs", "version": 1}}
+                "otherData": {"tracer": "repro.obs", "version": 2,
+                              "counters": dict(self.counters)}}
 
     def export(self, path: str | None = None) -> dict:
         """Chrome trace-event payload; also written to ``path`` if given."""
@@ -188,9 +157,6 @@ class SpanTracer:
             with open(path, "w") as f:
                 json.dump(payload, f)
         return payload
-
-    def __len__(self) -> int:
-        return 2 * len(self._spans) + len(self._events)
 
 
 # --------------------------------------------------------------------------
@@ -201,9 +167,15 @@ _CURRENT: SpanTracer | None = None
 
 def current_tracer() -> SpanTracer | None:
     """The installed tracer, or None (the default: tracing off). Engines
-    check this exactly once per run and skip every trace branch when it
-    is None — the untraced hot path stays sync-free."""
+    check this once per run and take the untraced path when it is
+    None."""
     return _CURRENT
+
+
+def optional_span(tr: SpanTracer | None, name: str, **args: Any):
+    """``tr.span(name, **args)``, or a no-op context when ``tr`` is None
+    (for once-a-run spans; the loops branch on ``tr`` themselves)."""
+    return nullcontext() if tr is None else tr.span(name, **args)
 
 
 @contextmanager

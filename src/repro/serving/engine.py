@@ -251,17 +251,18 @@ class ServingEngine:
         """One protocol iteration. Returns False when fully idle.
 
         With a span tracer installed (``repro.obs.tracing()``) each
-        iteration emits a fenced ``schedule`` span (admit + window build
-        + wave-0 selection) and an ``execute`` span (prefill chunks +
-        the batched decode wave) — the same taxonomy the batch engines
+        iteration emits a ``repro.schedule`` span (admit + window build
+        + wave-0 selection) and a ``repro.execute`` span (prefill chunks
+        + the batched decode wave) — the same names the batch engines
         use, so serving traces render through ``report.py trace``. The
-        untraced path is guarded by one ``current_tracer()`` check."""
+        spans never fence. The untraced path is guarded by one
+        ``current_tracer()`` check."""
         tr = current_tracer()
         if tr is None:
             self._admit()
             wave = self._schedule_wave(self._build_window())
         else:
-            with tr.span("schedule", index=self.iterations):
+            with tr.span("repro.schedule", index=self.iterations):
                 self._admit()
                 wave = self._schedule_wave(self._build_window())
         if not wave:
@@ -272,11 +273,10 @@ class ServingEngine:
         if tr is None:
             self._exec_wave(prefills, decodes)
         else:
-            with tr.span("execute", index=self.iterations,
-                         prefills=len(prefills), decodes=len(decodes)) as sp:
+            with tr.span("repro.execute", index=self.iterations,
+                         prefills=len(prefills), decodes=len(decodes),
+                         wave=len(prefills) + len(decodes)):
                 self._exec_wave(prefills, decodes)
-                jax.block_until_ready(self.states)
-                sp.args["wave"] = len(prefills) + len(decodes)
         self.prefill_tasks += len(prefills)
         self.decode_tasks += len(decodes)
         self.iterations += 1
@@ -297,14 +297,13 @@ class ServingEngine:
                 if it > max_iterations:
                     raise RuntimeError("engine did not converge")
             return self.finished
-        with tr.span("run", engine="serving", window=self.n_slots,
+        with tr.span("repro.run", engine="serving", window=self.n_slots,
                      total_tasks=0) as sp:
             it = 0
             while self.step():
                 it += 1
                 if it > max_iterations:
                     raise RuntimeError("engine did not converge")
-            jax.block_until_ready(self.states)
             sp.args["total_tasks"] = self.prefill_tasks + self.decode_tasks
         return self.finished
 

@@ -6,8 +6,11 @@ Pins the three contracts docs/observability.md promises:
     and engine stats come back as host-native Python scalars either way;
   * tracing ON does not perturb the protocol — every registry engine
     stays bit-exact vs the sequential oracle with a tracer installed,
-    and the export passes the Chrome trace-event schema validator
-    (matched B/E spans, monotone timestamps, known phases);
+    a traced run never fences and reads the device only in its
+    read-back (counted by ``host_syncs``), its spans land on the
+    profiler's host plane on the profiler's clock, and the export passes
+    the Chrome trace-event schema validator (matched B/E spans, monotone
+    timestamps, known phases);
   * the stats registry is the single schema authority — undeclared keys
     are rejected at the ``finalize_stats`` boundary, declared ones are
     normalized to their declared host types.
@@ -126,32 +129,44 @@ def test_tracing_off_by_default():
 
 
 def test_span_tracer_subdivide_and_export(tmp_path):
+    """Deferred facts: a span keeps device values by reference and reads
+    them only when the trace is rendered; the export carries them, the
+    counters, and validates."""
+    import jax.numpy as jnp
+
     from repro.obs import SpanTracer, validate_chrome_trace
 
+    reads = []
+
+    def widths(lv, n):
+        reads.append(1)
+        return {"n_waves": int(n), "widths": [int(x) for x in lv]}
+
     tr = SpanTracer()
-    with tr.span("run", engine="test") as run:
-        with tr.span("execute", index=0) as sp:
+    with tr.span("repro.run", engine="test") as run:
+        with tr.span("repro.execute", index=0) as sp:
             pass
-        sp.args["n_waves"] = 2          # args mutable after exit
-        slots = tr.subdivide(sp, "wave", [3, 1],
-                             [{"level": 0}, {"level": 1}])
-    assert run.t1 is not None
-    assert len(slots) == 2
-    # width-proportional attribution covers the parent span exactly
-    assert slots[0][0] == pytest.approx(sp.t0)
-    assert slots[0][1] == pytest.approx(3 * slots[1][1])
-    assert slots[1][0] + slots[1][1] == pytest.approx(sp.t1)
+        sp.defer(widths, jnp.array([3, 1]), jnp.int32(2))
+        sp.args["rung"] = "split"         # args mutable after exit
+        tr.count("host_syncs", 2)
+        tr.count("host_syncs")
+    assert run.t0 <= sp.t0 <= sp.t1 <= run.t1
+    assert not reads, "deferred facts read before export"
     path = tmp_path / "t.json"
     payload = tr.export(str(path))
+    assert reads == [1]
     assert validate_chrome_trace(payload) == len(payload["traceEvents"])
     on_disk = json.loads(path.read_text())
     assert validate_chrome_trace(on_disk)
-    waves = [e for e in on_disk["traceEvents"] if e["name"] == "wave"]
-    assert [w["args"]["level"] for w in waves] == [0, 1]
-    assert all(w["args"]["attributed"] for w in waves)
+    assert on_disk["otherData"]["counters"] == {"host_syncs": 3}
     execs = [e for e in on_disk["traceEvents"]
-             if e["name"] == "execute" and e["ph"] == "B"]
-    assert execs[0]["args"]["n_waves"] == 2
+             if e["name"] == "repro.execute" and e["ph"] == "B"]
+    assert execs[0]["args"] == {"index": 0, "rung": "split", "n_waves": 2,
+                                "widths": [3, 1]}
+    # ts in µs on the profiler's (epoch) clock
+    assert execs[0]["ts"] == pytest.approx(sp.t0 / 1e3)
+    tr.export()
+    assert reads == [1], "deferred facts are read once"
 
 
 def test_validator_rejects_malformed():
@@ -208,11 +223,16 @@ def test_traced_run_bit_exact_and_valid(ename):
     payload = tr.export()
     validate_chrome_trace(payload)
     names = {e["name"] for e in payload["traceEvents"]}
-    assert {"run", "execute"} <= names
+    assert {"repro.run", "repro.execute"} <= names
     if ename != "sequential":
-        assert {"schedule", "wave"} <= names
+        assert {"repro.schedule", "repro.readback"} <= names
     if ename.endswith("_overlap"):
-        assert "boundary" in names
+        assert "repro.boundary" in names
+        bnd = [e["args"] for e in payload["traceEvents"]
+               if e["name"] == "repro.boundary" and e["ph"] == "B"]
+        assert len(bnd) == stats["n_boundaries"]
+        assert (max(a["overlap_depth"] for a in bnd)
+                == stats["max_overlap_depth"])
     # untraced runs record nothing: the tracer we never installed for
     # plain_out doesn't exist; a fresh run outside tracing() adds no
     # events to the old tracer either
@@ -222,8 +242,9 @@ def test_traced_run_bit_exact_and_valid(ename):
 
 
 def test_trace_wave_widths_match_schedule():
-    """Wave spans carry the schedule's real widths: they sum to the
-    task total, and each window's widths sum to its task count."""
+    """Each execute span's deferred ``widths`` list holds one width per
+    executed wave: all of them sum to the task total, each window's to
+    its task count, and there are as many as the run's waves."""
     from repro.engine import make_engine
     from repro.obs import tracing
 
@@ -232,9 +253,138 @@ def test_trace_wave_widths_match_schedule():
     eng = make_engine("wavefront", m, window=16)
     with tracing() as tr:
         _, stats = eng.run(st0, 40, seed=BASE_SEED + 2)
-    waves = [e for e in tr.events() if e["name"] == "wave"]
-    assert len(waves) == stats["total_waves"]
-    assert sum(e["args"]["width"] for e in waves) == stats["total_tasks"]
+    execs = [e["args"] for e in tr.events()
+             if e["name"] == "repro.execute" and e["ph"] == "B"]
+    assert len(execs) == stats["n_windows"]
+    for a in execs:
+        assert len(a["widths"]) == a["n_waves"]
+        assert sum(a["widths"]) == a["count"]
+        assert all(w > 0 for w in a["widths"])
+    assert sum(len(a["widths"]) for a in execs) == stats["total_waves"]
+    assert sum(sum(a["widths"]) for a in execs) == stats["total_tasks"]
+
+
+@pytest.mark.parametrize("ename", ["wavefront", "wavefront_overlap"])
+def test_traced_run_never_fences(ename, monkeypatch):
+    """A traced run waits for the device nowhere but in its read-back:
+    no ``block_until_ready``, no device value read before
+    ``repro.readback``, and ``host_syncs`` counts the arrays read there.
+    It stays bit-exact, with the untraced run's stats."""
+    import jax.numpy as jnp
+    from jax._src.array import ArrayImpl
+
+    from repro.core import ProtocolConfig, run_oracle
+    from repro.engine import make_engine
+    from repro.obs import current_tracer, tracing
+
+    m = _voter()
+    st0 = m.init_state(jax.random.key(BASE_SEED + 1))
+    oracle = run_oracle(m, st0, 40, seed=BASE_SEED + 2,
+                        config=ProtocolConfig(window=16, strict=True))
+    eng = make_engine(ename, m, window=16, strict=True)
+    _, plain_stats = eng.run(st0, 40, seed=BASE_SEED + 2)
+
+    def fence(*a, **k):
+        raise AssertionError("a traced run fenced")
+
+    reads: dict = {}     # id -> (array, innermost open span)
+    value = ArrayImpl._value
+
+    def traced_value(self):
+        tr = current_tracer()
+        if tr is not None and id(self) not in reads:
+            open_ = [s.name for s in tr.spans if s.t1 is None]
+            reads[id(self)] = (self, open_[-1] if open_ else None)
+        return value.fget(self)
+
+    monkeypatch.setattr(jax, "block_until_ready", fence)
+    monkeypatch.setattr(ArrayImpl, "block_until_ready", fence)
+    monkeypatch.setattr(ArrayImpl, "_value", property(traced_value))
+    with tracing() as tr:
+        out, stats = eng.run(st0, 40, seed=BASE_SEED + 2)
+    monkeypatch.undo()
+    assert {where for _, where in reads.values()} == {"repro.readback"}
+    n_reads = stats["n_windows"] + 4 * stats.get("n_boundaries", 0)
+    assert tr.counters["host_syncs"] == len(reads) == n_reads
+    assert stats == plain_stats
+    for a, b in zip(jax.tree_util.tree_leaves(out),
+                    jax.tree_util.tree_leaves(oracle)):
+        assert bool(jnp.all(a == b)), f"{ename} diverged under tracing"
+
+
+def _profiled_run(ename, logdir):
+    """One traced run of ``ename`` under a CPU ``jax.profiler`` session:
+    (tracer, stats, the XSpace through ``bench.xplane``, the profile's
+    start on the epoch clock in ns)."""
+    from jax.profiler import ProfileData
+
+    from repro.engine import make_engine
+    from repro.obs import tracing
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from bench import xplane
+
+    m = _voter()
+    st0 = m.init_state(jax.random.key(BASE_SEED + 1))
+    eng = make_engine(ename, m, window=16)
+    eng.run(st0, 40, seed=BASE_SEED + 2)          # compile outside
+    with tracing() as tr, jax.profiler.trace(str(logdir)):
+        _, stats = eng.run(st0, 40, seed=BASE_SEED + 2)
+    path = xplane.find_xplane(str(logdir))
+    env = next(p for p in ProfileData.from_file(path).planes
+               if p.name == "Task Environment")
+    return tr, stats, xplane.load(path), dict(env.stats)[
+        "profile_start_time"]
+
+
+@pytest.mark.parametrize("ename", ["wavefront", "wavefront_overlap"])
+def test_profiler_trace_nests_engine_calls(ename, tmp_path):
+    """On the profiler's host plane ``repro.run`` encloses the window
+    spans and the read-back, and every jitted call of the run after its
+    set-up lies inside a schedule, boundary, execute or read-back
+    span."""
+    _, stats, t, _ = _profiled_run(ename, tmp_path)
+    by = {}
+    for e in t.host:
+        if e.name.startswith("repro."):
+            by.setdefault(e.name, []).append((e.start, e.end))
+    (run,) = by["repro.run"]
+    assert len(by["repro.schedule"]) == stats["n_windows"]
+    assert len(by["repro.execute"]) == stats["n_windows"]
+    assert len(by["repro.readback"]) == 1
+    assert len(by.get("repro.boundary", [])) == stats.get("n_boundaries", 0)
+    for name, spans in by.items():
+        assert all(run[0] <= a and b <= run[1] for a, b in spans), name
+    window = [s for n in ("repro.schedule", "repro.boundary",
+                          "repro.execute", "repro.readback")
+              for s in by.get(n, [])]
+    first = min(a for a, _ in by["repro.schedule"])
+    calls = [e for e in t.host if e.name.startswith("PjitFunction(")
+             and run[0] <= e.start and e.end <= run[1]]
+    names = {e.name for e in calls if e.start >= first}
+    assert {"PjitFunction(_execute)"} <= names
+    for e in calls:
+        assert e.end <= first or any(a <= e.start and e.end <= b
+                                     for a, b in window), e.name
+
+
+def test_span_clock_matches_profiler(tmp_path):
+    """The in-memory span times are on the profiler's clock: each span's
+    XSpace copy lies inside its ``[t0, t1]`` (10 µs of slack), and
+    starts a median of less than 10 µs after ``t0``."""
+    import statistics
+
+    tr, _, t, start_ns = _profiled_run("wavefront_overlap", tmp_path)
+    xs = sorted((e.start + start_ns, e.end + start_ns, e.name)
+                for e in t.host if e.name.startswith("repro."))
+    mine = sorted((s.t0, s.t1, s.name) for s in tr.spans)
+    assert [n for *_, n in xs] == [n for *_, n in mine]
+    slack = 10_000
+    for (a, b, _), (t0, t1, name) in zip(xs, mine):
+        assert t0 - slack <= a and b <= t1 + slack, name
+    assert statistics.median(a - t0 for (a, _, _), (t0, _, _)
+                             in zip(xs, mine)) < slack
 
 
 # --------------------------------------------------------------------------
@@ -263,18 +413,19 @@ def test_trace_smoke_sharded_8dev(tmp_path):
     from repro.obs import validate_chrome_trace
 
     validate_chrome_trace(payload)
-    gathers = [e for e in payload["traceEvents"]
-               if e["name"] == "halo_gather"]
-    assert gathers, "sharded trace must carry halo_gather spans"
-    for e in gathers:
-        assert e["args"]["rung"] in ("split", "window_halo", "pair_halo",
-                                     "full_state")
-        assert e["args"]["rows"] > 0
-        assert e["args"]["bytes"] >= e["args"]["rows"]
-    owned = [e["args"]["owned"] for e in payload["traceEvents"]
-             if e["name"] == "wave" and "owned" in e["args"]]
-    assert owned and all(len(o) == 8 for o in owned), (
-        "wave spans must carry 8 per-device owned-task counts")
+    execs = [e["args"] for e in payload["traceEvents"]
+             if e["name"] == "repro.execute" and e["ph"] == "B"]
+    assert execs, "sharded trace must carry execute spans"
+    for a in execs:
+        assert a["rung"] in ("split", "window_halo", "pair_halo",
+                             "full_state")
+        assert len(a["rows"]) == len(a["bytes"]) == a["n_waves"]
+        assert all(b >= r for r, b in zip(a["rows"], a["bytes"]))
+        assert len(a["owned"]) == a["n_waves"]
+        assert all(len(o) == 8 for o in a["owned"]), (
+            "execute spans must carry 8 per-device owned-task counts")
+    assert any(r > 0 for a in execs for r in a["rows"])
+    assert payload["otherData"]["counters"]["host_syncs"] > 0
     explain = run_py(["-m", "benchmarks.report", "explain", str(trace)])
     assert "Wave-size histogram" in explain
     assert "Comm ledger" in explain
