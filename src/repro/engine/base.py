@@ -21,6 +21,9 @@ then executed wave by wave — with a double-buffered *window pipeline*:
 the schedule for window t+1 is dispatched before the engine blocks on
 window t's execution, so the O(W²) record check of the next window
 overlaps the wave execution of the current one on the device queue.
+The barrier ``wavefront`` engine runs the same windows as one device
+program instead (``WavefrontEngine.run``); this host loop serves the
+sharded engines and the overlapped modes.
 """
 from __future__ import annotations
 

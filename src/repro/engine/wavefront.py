@@ -5,9 +5,10 @@ Streams the chain through windows of W tasks: each window is scheduled
 (prefix-conflict matrix through the conflict kernel, wave levels through
 the levels kernel — backend chosen at trace time) and executed one
 vectorized wave at a time. By default the window boundary is a
-conservative barrier, so cross-window ordering is trivially preserved;
-the shared ``WindowedEngine`` loop overlaps window t+1's scheduling with
-window t's execution.
+conservative barrier, so cross-window ordering is trivially preserved,
+and the whole run is one device program (``run_windows``): a
+``lax.while_loop`` over windows that schedules and executes each in
+turn, so the host dispatches once and reads back one wave count a run.
 
 With ``overlap=True`` (or the ``wavefront_overlap`` registry entry) the
 barrier falls: window k+1 is re-leveled against the carry-over conflict
@@ -23,8 +24,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.engine.base import WindowedEngine, register_engine
+from repro.obs.stats import finalize_stats
+from repro.obs.trace import current_tracer, optional_span
 
 
 @register_engine
@@ -46,12 +50,38 @@ class WavefrontEngine(WindowedEngine):
             return execute_window(model, state, recipes, valid,
                                   strict=self.strict, levels=levels)
 
-        # NB: no donation here — callers hand this engine externally owned
-        # state (and often reuse it for the oracle run); the sharded engine
-        # donates because it owns its device_put copy.
+        # NB: no donation in any executor here — callers hand this engine
+        # externally owned state (and often reuse it for the oracle run);
+        # the sharded engine donates because it owns its device_put copy.
         self._schedule = (jax.jit(self._schedule_window) if jit
                           else self._schedule_window)
         self._execute = jax.jit(_execute) if jit else _execute
+
+        def run_windows(state, seed, total):
+            """Every window of a barrier run: schedule window [t, t+W)
+            and execute it, until ``total`` tasks ran. ``t`` and
+            ``total`` are traced int32, so one executable serves every
+            run length; the run's key is made here from its uint32
+            ``seed``, so the host makes no call of its own for it.
+            Returns (state, total_waves)."""
+            w = self.window
+            base_key = jax.random.key(seed)
+
+            def body(carry):
+                t, st, waves = carry
+                recipes, valid, levels = self._schedule_window(
+                    base_key, t, jnp.minimum(w, total - t))
+                st, n_waves = execute_window(model, st, recipes, valid,
+                                             strict=self.strict,
+                                             levels=levels)
+                return t + w, st, waves + n_waves
+
+            _, state, waves = jax.lax.while_loop(
+                lambda c: c[0] < total, body,
+                (jnp.int32(0), state, jnp.int32(0)))
+            return state, waves
+
+        self._run_windows = jax.jit(run_windows) if jit else run_windows
 
         def _schedule_ov(base_key, start, count):
             recipes, valid, conf = self._schedule_window_ov(
@@ -90,6 +120,41 @@ class WavefrontEngine(WindowedEngine):
         self._execute_drain = lambda state, cur, lv: self._execute(
             state, (cur[0], cur[1], lv))
 
+    def run(self, state, total_tasks: int, *, seed: int = 0):
+        if self.overlap:
+            return super().run(state, total_tasks, seed=seed)
+        tr = current_tracer()
+        total_tasks = int(total_tasks)
+        n_windows = -(-total_tasks // self.window)
+        with optional_span(tr, "repro.run", engine=self.name,
+                           window=self.window, total_tasks=total_tasks,
+                           overlap=False):
+            # numpy scalars cross the jit boundary faster than Python
+            # ints; a uint32 seed makes the key jax.random.key(seed) makes
+            # on the host (32-bit mode keeps the low 32 bits of the seed)
+            args = (np.uint32(seed & 0xFFFFFFFF), np.int32(total_tasks))
+            if tr is None:
+                state, waves = self._run_windows(state, *args)
+            else:
+                with tr.span("repro.execute", windows=n_windows, start=0,
+                             count=total_tasks, device_loop=True) as sp:
+                    state, waves = self._run_windows(state, *args)
+                sp.defer(_loop_facts, waves)
+                tr.count("device_loop_windows", n_windows)
+            with optional_span(tr, "repro.readback", windows=n_windows):
+                # the run's one host sync
+                total_waves = int(waves)
+                stats = finalize_stats(self._extend_stats({
+                    "total_tasks": total_tasks,
+                    "n_windows": n_windows,
+                    "total_waves": total_waves,
+                    "mean_parallelism": total_tasks / max(total_waves, 1),
+                    "overlap": False,
+                }))
+            if tr is not None:
+                tr.count("host_syncs", 1 + self._stats_syncs())
+        return state, stats
+
     def _trace_parts(self, sched, levels=None):
         # barrier schedule carries its levels in slot 2; the overlapped
         # loop re-levels and passes them explicitly. Single device: no
@@ -112,6 +177,11 @@ class WavefrontOverlapEngine(WavefrontEngine):
 
     name = "wavefront_overlap"
     default_overlap = True
+
+
+def _loop_facts(waves):
+    """The device loop's execute span facts, read after the run."""
+    return {"n_waves": int(waves)}
 
 
 #: Backwards-compatible name for the pre-registry runner class.

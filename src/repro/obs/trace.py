@@ -3,7 +3,8 @@
 Records what the host does in each ``Engine.run``: one span for the run,
 for each window's schedule dispatch, each overlap boundary, each window's
 execute dispatch and the read-back of the run's statistics, plus
-counters (``host_syncs``: the blocking device-to-host reads of a run).
+counters (``host_syncs``: the blocking device-to-host reads of a run;
+``device_loop_windows``: the windows run inside a device loop).
 Exports Chrome trace-event JSON (``{"traceEvents": [...]}``, which
 Perfetto and ``chrome://tracing`` load).
 
@@ -37,7 +38,11 @@ Spans (pid 1, process "repro.protocol", one lane; all nest under
   repro.execute   one window's execute dispatch; after the run it holds
                   ``n_waves`` and ``widths`` (tasks per wave), and on the
                   sharded engines ``rung``, ``rows``, ``bytes`` and
-                  ``owned`` (tasks per device) per wave
+                  ``owned`` (tasks per device) per wave. The barrier
+                  wavefront engine schedules and executes every window in
+                  one device program: one ``repro.execute`` a run
+                  (``device_loop=True``, ``windows``; ``n_waves`` after
+                  the run, no ``widths``) and no ``repro.schedule``
   repro.readback  from the last dispatch to the stats dict
 
 Usage:

@@ -43,13 +43,56 @@ def test_make_engine_and_interface():
     assert out["opinions"].shape == st["opinions"].shape
 
 
-@pytest.mark.parametrize("total", [64, 100])  # full windows and partial tail
+# a single partial window, one full window, full windows, partial tails
+@pytest.mark.parametrize("total", [1, 31, 32, 64, 100, 133])
 def test_wavefront_engine_bitexact(total):
     m = VoterModel(watts_strogatz(64, 4, 0.2, jax.random.key(5)))
     st0 = m.init_state(jax.random.key(1))
     stats = assert_engine_matches_oracle(m, st0, total, engine="wavefront",
                                          window=32, seed=2)
     assert stats["total_waves"] >= 1
+    assert stats["n_windows"] == -(-total // 32)
+
+
+@pytest.mark.parametrize("seed", [-3, 2**31 + 5, 2**40 + 7])
+def test_wavefront_engine_bitexact_any_seed(seed):
+    """The device loop makes the run's key itself: seeds past 32 bits,
+    and negative ones, give the oracle's task stream too."""
+    m = VoterModel(watts_strogatz(64, 4, 0.2, jax.random.key(5)))
+    st0 = m.init_state(jax.random.key(1))
+    assert_engine_matches_oracle(m, st0, 70, engine="wavefront", window=32,
+                                 seed=seed)
+
+
+def test_wavefront_device_loop_compiles_once(monkeypatch):
+    """The barrier run is one device program whose run length is traced:
+    runs of three lengths trace the window loop once and share one
+    executable."""
+    m = VoterModel(ring(48, 4))
+    st0 = m.init_state(jax.random.key(0))
+    eng = make_engine("wavefront", m, window=16)
+    traces = []
+    schedule = eng._schedule_window
+    monkeypatch.setattr(eng, "_schedule_window",
+                        lambda *a: traces.append(1) or schedule(*a))
+    for total in (5, 40, 70):
+        _, stats = eng.run(st0, total, seed=1)
+        assert stats["total_tasks"] == total
+    assert len(traces) == 1
+    assert eng._run_windows._cache_size() == 1
+
+
+def test_wavefront_engine_does_not_clobber_caller_state():
+    """No donation: the caller's state is unchanged and still readable
+    after a run (callers replay from it, as the oracle comparison
+    does)."""
+    m = VoterModel(ring(48, 4))
+    st0 = m.init_state(jax.random.key(3))
+    before = np.asarray(st0["opinions"]).copy()
+    out, _ = make_engine("wavefront", m, window=16).run(st0, 64, seed=0)
+    assert not st0["opinions"].is_deleted()
+    assert (np.asarray(st0["opinions"]) == before).all()
+    assert not (np.asarray(out["opinions"]) == before).all()
 
 
 def test_run_engine_routes_by_config_and_kwarg():

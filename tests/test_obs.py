@@ -225,7 +225,9 @@ def test_traced_run_bit_exact_and_valid(ename):
     names = {e["name"] for e in payload["traceEvents"]}
     assert {"repro.run", "repro.execute"} <= names
     if ename != "sequential":
-        assert {"repro.schedule", "repro.readback"} <= names
+        assert "repro.readback" in names
+    # the barrier wavefront engine schedules inside its device loop
+    assert ("repro.schedule" in names) == (ename == "wavefront_overlap")
     if ename.endswith("_overlap"):
         assert "repro.boundary" in names
         bnd = [e["args"] for e in payload["traceEvents"]
@@ -242,34 +244,41 @@ def test_traced_run_bit_exact_and_valid(ename):
 
 
 def test_trace_wave_widths_match_schedule():
-    """Each execute span's deferred ``widths`` list holds one width per
-    executed wave: all of them sum to the task total, each window's to
-    its task count, and there are as many as the run's waves."""
+    """Each execute span of the overlapped loop (one a window) holds one
+    deferred width per executed wave: all of them sum to the task total,
+    and there are as many as the run's waves. A fused drain also runs
+    the next window's head tasks, so the tasks run by the end of window
+    i's span are at least the first i windows' and at most i + 1's."""
     from repro.engine import make_engine
     from repro.obs import tracing
 
     m = _voter()
     st0 = m.init_state(jax.random.key(BASE_SEED + 1))
-    eng = make_engine("wavefront", m, window=16)
+    eng = make_engine("wavefront_overlap", m, window=16)
     with tracing() as tr:
         _, stats = eng.run(st0, 40, seed=BASE_SEED + 2)
     execs = [e["args"] for e in tr.events()
              if e["name"] == "repro.execute" and e["ph"] == "B"]
     assert len(execs) == stats["n_windows"]
-    for a in execs:
+    counts = [a["count"] for a in execs] + [0]
+    done = 0
+    for i, a in enumerate(execs):
         assert len(a["widths"]) == a["n_waves"]
-        assert sum(a["widths"]) == a["count"]
         assert all(w > 0 for w in a["widths"])
+        done += sum(a["widths"])
+        assert sum(counts[:i + 1]) <= done <= sum(counts[:i + 2])
     assert sum(len(a["widths"]) for a in execs) == stats["total_waves"]
-    assert sum(sum(a["widths"]) for a in execs) == stats["total_tasks"]
+    assert done == stats["total_tasks"]
 
 
 @pytest.mark.parametrize("ename", ["wavefront", "wavefront_overlap"])
 def test_traced_run_never_fences(ename, monkeypatch):
     """A traced run waits for the device nowhere but in its read-back:
     no ``block_until_ready``, no device value read before
-    ``repro.readback``, and ``host_syncs`` counts the arrays read there.
-    It stays bit-exact, with the untraced run's stats."""
+    ``repro.readback``, and ``host_syncs`` counts the arrays read there:
+    the barrier wavefront engine's device loop reads one wave count a
+    run, and ``device_loop_windows`` counts the windows it ran. It stays
+    bit-exact, with the untraced run's stats."""
     import jax.numpy as jnp
     from jax._src.array import ArrayImpl
 
@@ -304,7 +313,15 @@ def test_traced_run_never_fences(ename, monkeypatch):
         out, stats = eng.run(st0, 40, seed=BASE_SEED + 2)
     monkeypatch.undo()
     assert {where for _, where in reads.values()} == {"repro.readback"}
-    n_reads = stats["n_windows"] + 4 * stats.get("n_boundaries", 0)
+    if ename == "wavefront":
+        n_reads = 1
+        assert tr.counters["device_loop_windows"] == stats["n_windows"]
+        (ex,) = [s for s in tr.spans if s.name == "repro.execute"]
+        assert ex.args["device_loop"]
+        assert ex.args["windows"] == stats["n_windows"]
+    else:
+        n_reads = stats["n_windows"] + 4 * stats["n_boundaries"]
+        assert "device_loop_windows" not in tr.counters
     assert tr.counters["host_syncs"] == len(reads) == n_reads
     assert stats == plain_stats
     for a, b in zip(jax.tree_util.tree_leaves(out),
@@ -342,16 +359,24 @@ def _profiled_run(ename, logdir):
 def test_profiler_trace_nests_engine_calls(ename, tmp_path):
     """On the profiler's host plane ``repro.run`` encloses the window
     spans and the read-back, and every jitted call of the run after its
-    set-up lies inside a schedule, boundary, execute or read-back
-    span."""
-    _, stats, t, _ = _profiled_run(ename, tmp_path)
+    set-up lies inside a schedule, boundary, execute or read-back span.
+    The barrier wavefront engine dispatches its device loop in one
+    execute span that covers every window."""
+    tr, stats, t, _ = _profiled_run(ename, tmp_path)
     by = {}
     for e in t.host:
         if e.name.startswith("repro."):
             by.setdefault(e.name, []).append((e.start, e.end))
     (run,) = by["repro.run"]
-    assert len(by["repro.schedule"]) == stats["n_windows"]
-    assert len(by["repro.execute"]) == stats["n_windows"]
+    if ename == "wavefront":
+        assert "repro.schedule" not in by
+        assert len(by["repro.execute"]) == 1
+        assert tr.counters["device_loop_windows"] == stats["n_windows"]
+        loop = "PjitFunction(run_windows)"
+    else:
+        assert len(by["repro.schedule"]) == stats["n_windows"]
+        assert len(by["repro.execute"]) == stats["n_windows"]
+        loop = "PjitFunction(_execute)"
     assert len(by["repro.readback"]) == 1
     assert len(by.get("repro.boundary", [])) == stats.get("n_boundaries", 0)
     for name, spans in by.items():
@@ -359,11 +384,11 @@ def test_profiler_trace_nests_engine_calls(ename, tmp_path):
     window = [s for n in ("repro.schedule", "repro.boundary",
                           "repro.execute", "repro.readback")
               for s in by.get(n, [])]
-    first = min(a for a, _ in by["repro.schedule"])
+    first = min(a for a, _ in window)
     calls = [e for e in t.host if e.name.startswith("PjitFunction(")
              and run[0] <= e.start and e.end <= run[1]]
     names = {e.name for e in calls if e.start >= first}
-    assert {"PjitFunction(_execute)"} <= names
+    assert loop in names
     for e in calls:
         assert e.end <= first or any(a <= e.start and e.end <= b
                                      for a, b in window), e.name

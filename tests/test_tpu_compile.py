@@ -112,25 +112,57 @@ def test_wave_levels_refuses_window_past_vmem():
                                  interpret=False)
 
 
-def test_sis_schedule_compiles_at_million_agents(one_chip, monkeypatch):
-    """The wavefront engine's jitted window schedule for SIS on a
-    10^6-agent Watts-Strogatz network, steered onto the compiled Pallas
-    kernels as on a chip (this process's backend is the CPU)."""
-    import repro.kernels as kernels
-    from repro.engine import make_engine
-    from repro.mabs.sis import SISModel
+@pytest.fixture(scope="module")
+def sis_net():
+    """SIS's 10^6-agent Watts-Strogatz network (max degree 9)."""
     from repro.topology import connect_isolated, watts_strogatz
 
     net = connect_isolated(watts_strogatz(10**6, 4, 0.1, jax.random.key(0)),
                            jax.random.key(1))
     assert net.max_degree + 1 == NR
+    return net
+
+
+def _sis_engine(net, monkeypatch):
+    """The wavefront engine for SIS on ``net``, steered onto the compiled
+    Pallas kernels as on a chip (this process's backend is the CPU)."""
+    import repro.kernels as kernels
+    from repro.engine import make_engine
+    from repro.mabs.sis import SISModel
+
     monkeypatch.setattr(kernels, "on_tpu", lambda: True)
     kernels.RESOLVED.clear()
-    eng = make_engine("wavefront", SISModel(net), window=256)
+    return make_engine("wavefront", SISModel(net), window=256)
+
+
+def _assert_schedule_kernels(compiled):
+    import repro.kernels as kernels
+
+    assert kernels.RESOLVED == {"conflict_matrix": "pallas",
+                                "wave_levels": "pallas"}
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_sis_schedule_compiles_at_million_agents(one_chip, sis_net,
+                                                 monkeypatch):
+    """The wavefront engine's jitted window schedule for SIS on a
+    10^6-agent Watts-Strogatz network."""
+    eng = _sis_engine(sis_net, monkeypatch)
     key = jax.eval_shape(lambda: jax.random.key(0))
     i32 = _spec(one_chip, (), jnp.int32)
     compiled = eng._schedule.lower(
         _spec(one_chip, key.shape, key.dtype), i32, i32).compile()
-    assert kernels.RESOLVED == {"conflict_matrix": "pallas",
-                                "wave_levels": "pallas"}
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    _assert_schedule_kernels(compiled)
+
+
+def test_sis_device_loop_compiles_at_million_agents(one_chip, sis_net,
+                                                    monkeypatch):
+    """The barrier engine's whole-run device loop (every window scheduled
+    and executed inside one ``while``) for SIS on the same network: both
+    scheduling kernels compile inside the loop body."""
+    eng = _sis_engine(sis_net, monkeypatch)
+    state = {"states": _spec(one_chip, (sis_net.n_nodes,), jnp.int8)}
+    compiled = eng._run_windows.lower(
+        state, _spec(one_chip, (), jnp.uint32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    _assert_schedule_kernels(compiled)
