@@ -8,7 +8,9 @@
            sample holds any state
   window   a closed loop of observation intervals on that same engine,
            each as long as the configuration records its observable
-           (``record_every_sweeps`` sweeps of ``n_agents`` tasks):
+           (``record_every_sweeps`` sweeps, each of the builder's
+           ``tasks_per_sweep`` tasks, the program's tasks that update
+           every agent once):
            ``eng.run(state, interval_tasks, seed=s_i)``, then one scalar
            observable of the new state read back to the host; ``s_i``
            comes from the run's seed and the interval's index
@@ -28,6 +30,7 @@
 from __future__ import annotations
 
 import json
+import numbers
 import shutil
 import statistics
 import sys
@@ -158,6 +161,20 @@ class Sample:
         return sorted(got, key=lambda g: g[0])
 
 
+def tasks_per_sweep(case: dict, kind: str) -> int:
+    """The builder's ``tasks_per_sweep``: how many of the program's tasks
+    update every agent once. Raises ``ValueError`` unless it is there and
+    a positive whole number."""
+    n = case.get("tasks_per_sweep")
+    if (not isinstance(n, numbers.Integral) or isinstance(n, bool)
+            or n <= 0):
+        raise ValueError(
+            f"the builder bench/models/{kind}.py must return "
+            f"'tasks_per_sweep', a positive int (the program's tasks that "
+            f"update every agent once); it returned {n!r}")
+    return int(n)
+
+
 def check_size(traffic: dict, n_tasks: int) -> int:
     """How many window intervals the reference replays (the last one
     included): what ``check_tasks`` holds, at least 2, at most
@@ -233,12 +250,14 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
 
     tr = cell.traffic
     window = int(tr["window"])
-    n_tasks = int(cell.config["n_agents"]) * int(
-        cell.config["record_every_sweeps"])
     c0 = compiles.now()
     case = cell.builder().build(cell.config, derive(seed, STATE))
+    sweep = tasks_per_sweep(case, cell.kind)
+    sweeps = int(cell.config["record_every_sweeps"])
+    n_tasks = sweep * sweeps
     log(f"{workload}: {case['describe']}; engine {tr['engine']} W={window}, "
-        f"{n_tasks} tasks per interval; compile cache {cache}")
+        f"{n_tasks} tasks per interval ({sweeps} sweep(s) of {sweep} tasks); "
+        f"compile cache {cache}")
     call = system(case, tr, cell)
     observe = case["observe"]
     sample = Sample(check_size(tr, n_tasks) - 1, seed)

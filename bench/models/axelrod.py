@@ -27,6 +27,8 @@ def build(cfg: dict, state_seed: int) -> dict:
         "state": init(jax.random.key(state_seed)),
         # sum of all traits: moves with every copied feature
         "observe": jax.jit(lambda s: jnp.sum(s["traits"])),
+        # one task updates one agent: a sweep is n_agents tasks
+        "tasks_per_sweep": n,
         "footprint": (2, 1),
         "ref_data": {"n_agents": n, "n_features": f,
                      "omega": float(cfg["omega"])},

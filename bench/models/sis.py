@@ -81,6 +81,8 @@ def build(cfg: dict, state_seed: int) -> dict:
         "state": init(jax.random.key(state_seed)),
         # infected share, the epidemic's prevalence
         "observe": jax.jit(lambda s: jnp.mean(s["states"] == 1)),
+        # one task updates one agent: a sweep is n_agents tasks
+        "tasks_per_sweep": n,
         "footprint": (nbrs.shape[1] + 1, 1),
         "ref_data": {"neighbors": nbrs, "degrees": deg,
                      "beta": float(cfg["beta"]), "gamma": float(cfg["gamma"])},

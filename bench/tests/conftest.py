@@ -64,6 +64,57 @@ def add_tiny_cells(root: Path) -> list[str]:
     return cells
 
 
+#: a builder of a kind whose task is not one agent's update: it wraps
+#: SIS's and states a sweep as ``n_agents // 4`` tasks (``{sweep}``), or
+#: leaves the key out (``None``)
+WRAPPED_SIS = """from pathlib import Path
+
+from bench import manifest
+
+_sis = manifest.load_module(Path(__file__).with_name("sis.py"),
+                            "bench_model_sis_wrapped")
+
+
+def build(cfg, state_seed):
+    case = _sis.build(cfg, state_seed)
+    case.pop("tasks_per_sweep")
+    sweep = {sweep}
+    if sweep is not None:
+        case["tasks_per_sweep"] = sweep
+    return case
+"""
+
+
+def add_wrapped_sis_cell(root: Path, kind: str, sweep: str,
+                         record_every_sweeps: int) -> str:
+    """Add to the copy at ``root`` a tiny SIS configuration of kind
+    ``kind``, whose builder wraps SIS's and returns ``tasks_per_sweep =
+    <sweep>`` (an expression in ``cfg``, or ``"None"`` for no key), and
+    its cell on the ``tiny`` traffic; returns the cell's name."""
+    bench = root / "bench"
+    (bench / "models" / f"{kind}.py").write_text(
+        WRAPPED_SIS.format(sweep=sweep))
+    shutil.copy(bench / "reference" / "sis.py",
+                bench / "reference" / f"{kind}.py")
+    cfg = json.loads((bench / "configs" / "sis_ws_1m.json").read_text())
+    cfg.update(kind=kind, n_agents=1024,
+               record_every_sweeps=record_every_sweeps)
+    (bench / "configs" / f"{kind}.json").write_text(json.dumps(cfg))
+    man = json.loads((root / "BENCHMARK.json").read_text())
+    man["configs"].append({"name": kind, "source": "a test",
+                           "file": f"bench/configs/{kind}.json",
+                           "reduced": ["n_agents", "record_every_sweeps"],
+                           "why": "a test"})
+    cell = f"{kind}.tiny"
+    man["workloads"].append({"name": cell, "config": kind,
+                             "traffic": "tiny", "chips": 1, "why": "a test"})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(man, indent=1))
+    return cell
+
+
 @pytest.fixture(autouse=True)
 def off_chip(monkeypatch):
     import jax

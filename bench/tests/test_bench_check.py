@@ -7,12 +7,15 @@ it. The cells run on one chip, so the fault of an exchange between chips
 left out cannot arise in them.
 """
 import time
+import types
 
 import jax
 import pytest
 
-from bench import harness
+from bench import harness, manifest
 from bench.control import control_system
+from bench.tests.conftest import (add_tiny_cells, add_wrapped_sis_cell,
+                                   copy_bench)
 
 
 def _run(root, cell, system=harness.program_system, seed=2**31 + 12345):
@@ -68,6 +71,62 @@ def test_control_is_not_correct(tiny_root, cell):
     out = _run(tiny_root, cell, control_system)
     assert out["correct"] is False
     assert out["check"]["mismatches"]["value"] > 0
+
+
+def _copy_with(tmp_path, kind, sweep, record_every_sweeps):
+    root = copy_bench(tmp_path)
+    add_tiny_cells(root)
+    return root, add_wrapped_sis_cell(root, kind, sweep, record_every_sweeps)
+
+
+def test_interval_is_the_builders_sweeps(tmp_path, monkeypatch):
+    """An interval is ``record_every_sweeps`` sweeps of the builder's
+    ``tasks_per_sweep``, not of ``n_agents``: here 2 sweeps of 1024 // 4
+    tasks; the window runs them and the reference replays them."""
+    root, cell = _copy_with(tmp_path, "sis_quarter_sweep",
+                            "int(cfg['n_agents']) // 4", 2)
+    items, calls, replayed = [], [], []
+    sample_items = harness.Sample.items
+
+    def spy_items(self):
+        items.extend(sample_items(self))
+        return list(items)
+
+    monkeypatch.setattr(harness.Sample, "items", spy_items)
+    cell_reference = manifest.Cell.reference
+
+    def reference(self):
+        class Spy(cell_reference(self).Reference):
+            def replay(self, state, seed, n):
+                replayed.append(n)
+                return super().replay(state, seed, n)
+
+        return types.SimpleNamespace(Reference=Spy)
+
+    monkeypatch.setattr(manifest.Cell, "reference", reference)
+
+    def system(case, traffic, cell):
+        run = harness.program_system(case, traffic, cell)
+
+        def counted(state, n_tasks, *, seed):
+            calls.append(n_tasks)
+            return run(state, n_tasks, seed=seed)
+
+        return counted
+
+    out = _run(root, cell, system)
+    assert out["correct"] is True, out["check"]
+    warm, *rest = [n for _, _, n, _, _ in items]
+    assert warm == 2 * 64 and rest and set(rest) == {1024 // 2}
+    assert replayed == [n for _, _, n, _, _ in items]
+    assert calls[0] == warm and set(calls[1:]) == {1024 // 2}
+
+
+@pytest.mark.parametrize("sweep", ["None", "0", "float(cfg['n_agents'])"])
+def test_builder_without_a_sweep_length_is_refused(tmp_path, sweep):
+    root, cell = _copy_with(tmp_path, "sis_bad_sweep", sweep, 1)
+    with pytest.raises(ValueError, match="tasks_per_sweep"):
+        _run(root, cell)
 
 
 def test_traced_run_reports_per_layer_metrics(tiny_root):

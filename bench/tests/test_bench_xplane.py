@@ -108,6 +108,34 @@ def test_layers_found_by_op_name():
                                  "wave execution:while": 300e-9})
 
 
+def test_wave_loop_nested_in_a_window_loop():
+    """As in a device loop over windows: an outer ``while`` holds each
+    window's schedule kernels and its wave loop, itself a ``while``. Only
+    the inner loops are wave execution; the breakdown keeps its labels."""
+    ops = [("%while.1 = (s32[], s8[1000000]) while(...)", 100, 800, {}),
+           ("%wave_levels_pallas.2 = s32[256,1] custom-call(...)",
+            150, 40, {}),
+           ("%while.3 = (s32[], s8[1000000]) while(...)", 200, 200, {}),
+           ("%fusion.4 = s8[1000000] fusion(...)", 250, 50, {}),  # in it
+           ("%conflict_matrix_pallas.5 = s32[256,256] custom-call(...)",
+            450, 30, {}),
+           ("%while.6 = (s32[], s8[1000000]) while(...)", 500, 200, {})]
+    planes = [("/device:TPU:0", [("XLA Ops", ops)]),
+              ("/host:CPU", [("python", [("bench.interval", 0, 1000, {})])])]
+    t = xplane.from_profile(ProfileData.from_serialized_xspace(
+        trim_trace.serialize(planes)))
+    r, f = _readers(), _facts()
+    # the two inner loops, 400 ns over 4 waves; not the outer 800 ns
+    assert r["execute_us_per_wave"](t, f) == pytest.approx(0.1)
+    assert xplane.nested_loops(t.chips[0]) == [(200, 400), (500, 700)]
+    assert r["schedule_us_per_window"](t, f) == pytest.approx(0.035)
+    assert r["device_idle_pct"](t, f) == pytest.approx(20.0)
+    ops = dict(map(tuple, t.device_ops(harness._layers(ROOT))))
+    assert ops == pytest.approx({"schedule:wave_levels_pallas": 40e-9,
+                                 "schedule:conflict_matrix_pallas": 30e-9,
+                                 "wave execution:while": 800e-9})
+
+
 def test_readers_return_nothing_without_a_device():
     planes = [p for p in synthetic() if p[0] == "/host:CPU"]
     t = xplane.from_profile(ProfileData.from_serialized_xspace(

@@ -11,8 +11,9 @@ of event matter here:
               and no stat carries its JAX name-scope path, so a layer is
               found by the op's name (a Pallas kernel is named after its
               wrapper, e.g. ``wave_levels_pallas``; the wave loop is the
-              ``while`` op) or, where a stat does carry it, by a
-              ``protocol.*`` scope
+              ``while`` op, and where the windows run in a device loop of
+              their own, a ``while`` op inside another one) or, where a
+              stat does carry it, by a ``protocol.*`` scope
   host spans  the benchmark's own ``jax.profiler.TraceAnnotation`` spans
               (``bench.interval`` around each ``Engine.run`` call,
               ``bench.observable`` around the read-back), on the host plane
@@ -39,6 +40,8 @@ HOST_PLANE = "/host:CPU"
 DISPATCH_PREFIX = "PjitFunction("
 #: stats that may carry an op's scope path, in the order tried
 SCOPE_STATS = ("tf_op", "name_scope", "long_name")
+#: the op of a device loop (``lax.while_loop``)
+LOOP_OP = "while"
 
 
 @dataclass
@@ -82,6 +85,33 @@ def length(merged) -> float:
     return sum(b - a for a, b in merged)
 
 
+def intersect(a, b):
+    """Intersection of two merged, sorted interval lists."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if lo < hi:
+            out.append([lo, hi])
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def nested_loops(ops) -> list:
+    """(start, end) of the ``while`` ops in ``ops`` that run inside
+    another ``while`` op: a wave loop inside a device loop over windows."""
+    loops = sorted(((o.start, o.end) for o in ops if o.op == LOOP_OP),
+                   key=lambda x: (x[0], -x[1]))
+    out, reach = [], float("-inf")
+    for a, b in loops:
+        if reach >= b:
+            out.append((a, b))
+        reach = max(reach, b)
+    return out
+
+
 def matches(op: "Event", names) -> bool:
     """Whether ``op`` is one of ``names``: by its op name, or by a
     component of its scope path."""
@@ -113,18 +143,24 @@ class Trace:
     def window_s(self) -> float:
         return (self.t1 - self.t0) * 1e-9
 
-    def busy_s(self, names=None) -> float | None:
+    def busy_s(self, names=None, inner_loops=False) -> float | None:
         """Seconds in the window in which an op ran on the device (only
         ops under one of the scopes ``names``, when given), averaged over
-        the chips. None when the trace holds no device."""
+        the chips. With ``inner_loops``, on a chip where a ``while`` op
+        runs inside another, only the time inside such nested ones counts
+        (the wave loops, not the device loop over windows around them).
+        None when the trace holds no device."""
         if not self.chips:
             return None
         tot = 0.0
         for ops in self.chips:
             sel = ops if names is None else [
                 o for o in ops if matches(o, names)]
-            tot += length(union(((o.start, o.end) for o in sel),
-                                self.t0, self.t1))
+            busy = union(((o.start, o.end) for o in sel), self.t0, self.t1)
+            inner = nested_loops(ops) if inner_loops else []
+            if inner:
+                busy = intersect(busy, union(inner))
+            tot += length(busy)
         return tot / len(self.chips) * 1e-9
 
     def dispatch_s(self) -> float:
